@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """§Perf cell C: the paper's profile-based searcher autotunes the
 DISTRIBUTED STEP CONFIG of qwen2.5-3b train_4k on the production mesh,
 through the public ``repro.tuning`` API.
@@ -10,18 +7,21 @@ sample of the step space and fits the TP -> PC_ops model.  Autotuning:
 profile -> bottleneck -> ΔPC -> biased step, against REAL compiles, driven
 ask-tell.  Compared with random search at the same budget.
 
-    PYTHONPATH=src python examples/autotune_train_step.py \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/autotune_train_step.py \
         [--arch qwen2.5-3b] [--budget 10] [--out step_tune.json]
 """
-import argparse      # noqa: E402
-import json          # noqa: E402
-import time          # noqa: E402
+import argparse
+import json
+import time
 
-from repro.core.step_tuner import CompiledStepEvaluator  # noqa: E402
-from repro.tuning import TuningSession                   # noqa: E402
+from repro.core.step_tuner import CompiledStepEvaluator
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import use_host_devices
+from repro.tuning import TuningSession
 
 
 def main():
+    use_host_devices(512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--shape", default="train_4k")
@@ -31,6 +31,7 @@ def main():
     ap.add_argument("--save-model", default=None,
                     help="also write the trained TP->PC model JSON artifact")
     args = ap.parse_args()
+    enable_compile_cache()
 
     t0 = time.time()
     ev_train = CompiledStepEvaluator(args.arch, args.shape)

@@ -86,6 +86,25 @@ PORTABILITY_SET: Tuple[str, ...] = ("tpu_v4", "tpu_v5e", "tpu_v5p", "tpu_v6e")
 # Production dry-run target.
 PRODUCTION = TPU_V5E
 
+# ``jax.Device.device_kind`` as each registered generation reports it.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v4": "tpu_v4",
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5": "tpu_v5p",
+    "TPU v6 lite": "tpu_v6e",
+}
+
+
+def spec_for_device(device) -> HardwareSpec:
+    """The registered spec of a live ``jax.Device``; a kind with no spec
+    is an error, never a default (its measurements would be filed under
+    another chip's name)."""
+    kind = device.device_kind
+    if kind not in DEVICE_KINDS:
+        raise KeyError(f"no hardware spec for device kind {kind!r}; "
+                       f"registered: {sorted(DEVICE_KINDS)}")
+    return SPECS[DEVICE_KINDS[kind]]
+
 
 def _squash(name: str) -> str:
     """Alphanumeric-only lowercase form used for drift-tolerant matching."""

@@ -105,6 +105,21 @@ def param_shardings(mesh: Mesh, rules: ShardingRules, specs_tree,
     )
 
 
+def train_state_shardings(mesh: Mesh, rules: ShardingRules, specs_tree,
+                          state_abstract):
+    """``TrainState`` shardings: params and both Adam moments follow the
+    params' logical specs; the step counters are replicated."""
+    rep = NamedSharding(mesh, P())
+    opt = state_abstract.opt
+    return type(state_abstract)(
+        params=param_shardings(mesh, rules, specs_tree,
+                               state_abstract.params),
+        opt=type(opt)(m=param_shardings(mesh, rules, specs_tree, opt.m),
+                      v=param_shardings(mesh, rules, specs_tree, opt.v),
+                      count=rep),
+        step=rep)
+
+
 def make_act_resolver(mesh: Mesh, rules: ShardingRules):
     """Resolver for distributed/api.activation_sharding."""
     def resolve(x, logical):

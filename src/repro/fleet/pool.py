@@ -18,12 +18,9 @@ worker that another job could use:
   kernels, RPCs to devices).
 * ``SubprocessWorkerPool`` — one persistent worker *process* per lane,
   speaking JSON-lines over stdin/stdout (``repro.fleet.worker_main``).
-  Workers can bring up their own multi-device jax runtime (the
-  ``launch/mesh.py`` host-mesh machinery via
-  ``--xla_force_host_platform_device_count``), which is the shape of a real
-  per-device fleet backend; work items must carry a serializable
-  ``payload`` (registry kernel + input + hardware + config index) instead
-  of a closure.
+  Workers price the cost model and never touch a device; work items must
+  carry a serializable ``payload`` (registry kernel + input + hardware +
+  config index) instead of a closure.
 
 Failure contract: a failed empirical test is DATA, not an exception.
 ``collect()`` never raises on a lane failure — it returns a
@@ -302,9 +299,7 @@ class SubprocessWorkerPool:
     """``workers`` persistent evaluation processes over JSON-lines pipes.
 
     Each worker runs ``python -m repro.fleet.worker_main`` with its own
-    interpreter (and, with ``devices_per_worker > 0``, its own jax host
-    runtime of that many devices brought up through the ``launch/mesh.py``
-    host-mesh machinery).  Work items must carry a ``payload`` naming a
+    interpreter.  Work items must carry a ``payload`` naming a
     registered kernel workload; results stream back on a reader thread per
     worker, so ``collect`` sees completions in real finish order across the
     whole pool.
@@ -319,8 +314,7 @@ class SubprocessWorkerPool:
     seeing the fleet-dead condition.
     """
 
-    def __init__(self, workers: int = 2, devices_per_worker: int = 0,
-                 startup_timeout: float = 120.0):
+    def __init__(self, workers: int = 2, startup_timeout: float = 120.0):
         if workers < 1:
             raise ValueError(f"workers must be >= 1, got {workers}")
         self.workers = int(workers)
@@ -342,8 +336,7 @@ class SubprocessWorkerPool:
         env = dict(os.environ)
         env["PYTHONPATH"] = src_dir + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        cmd = [sys.executable, "-m", "repro.fleet.worker_main",
-               "--devices", str(int(devices_per_worker))]
+        cmd = [sys.executable, "-m", "repro.fleet.worker_main"]
         for w in range(self.workers):
             p = subprocess.Popen(cmd, stdin=subprocess.PIPE,
                                  stdout=subprocess.PIPE, env=env, text=True,
@@ -353,7 +346,7 @@ class SubprocessWorkerPool:
                                  daemon=True)
             t.start()
             self._readers.append(t)
-        # handshake: a ping per worker proves imports/devices came up
+        # handshake: a ping per worker proves its imports came up
         try:
             for p in self._procs:
                 p.stdin.write(json.dumps({"op": "ping"}) + "\n")

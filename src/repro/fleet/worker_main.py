@@ -6,13 +6,10 @@ registered kernel workload (``{"kernel", "input", "hw", "index", "uid",
 prices it through the cost model on the named hardware, and replies with
 ``{"uid", "runtime", "cost"}`` (plus ``ops``/``stress`` when profiled).
 
-With ``--devices N`` the worker brings up its own N-device jax host runtime
-(``--xla_force_host_platform_device_count``) and builds a mesh through the
-``launch/mesh.py`` machinery — the same per-process multi-device shape the
-8-device dry-run integration uses, so a real device-backed ``run()``
-payload drops in without changing the pool protocol.
+The worker never imports jax: several lanes run side by side, and a chip
+belongs to one process at a time.
 
-Protocol extras: ``{"op": "ping"}`` → ``{"op": "pong", "devices": n}``
+Protocol extras: ``{"op": "ping"}`` → ``{"op": "pong"}``
 (startup handshake), ``{"op": "shutdown"}`` or EOF → exit.  Errors are
 reported per-request (``{"uid", "error", ...}``), never by crashing the
 worker.  ``attempt`` is echoed back verbatim so the pool can correlate
@@ -27,33 +24,12 @@ kind ``"lane"``).
 """
 from __future__ import annotations
 
-import argparse
 import json
 import sys
 import time
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--devices", type=int, default=0,
-                    help="bring up a jax host runtime with this many "
-                    "devices (0: pure-numpy cost-model evaluation)")
-    args = ap.parse_args(argv)
-
-    mesh = None
-    n_devices = 0
-    if args.devices > 0:
-        import os
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            f"--xla_force_host_platform_device_count={args.devices}")
-        import jax
-
-        from repro.launch.mesh import make_host_mesh
-
-        mesh = make_host_mesh(data=args.devices)
-        n_devices = len(jax.devices())
-
+def main() -> int:
     from repro.core import costmodel, hwspec
     from repro.kernels.registry import BENCHMARKS
 
@@ -68,8 +44,7 @@ def main(argv=None) -> int:
         if op == "shutdown":
             break
         if op == "ping":
-            print(json.dumps({"op": "pong", "devices": n_devices,
-                              "mesh": bool(mesh)}), flush=True)
+            print(json.dumps({"op": "pong"}), flush=True)
             continue
         out = {"uid": req.get("uid"), "attempt": int(req.get("attempt", 0))}
         if req.get("sim_crash"):

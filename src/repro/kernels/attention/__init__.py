@@ -25,4 +25,5 @@ def _benchmark() -> KernelBenchmark:
         default_input=space.DEFAULT_INPUT,
         inputs={"default": space.DEFAULT_INPUT},
         make_args=_make_args, run=ops.run, ref=attention_ref,
+        default_config={"BLOCK_Q": 256, "BLOCK_K": 256, "KEEP_P": 0, "Q_PREFETCH": 1},
     )

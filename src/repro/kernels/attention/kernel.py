@@ -14,8 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
-from repro.kernels.common import cdiv
+from repro.kernels.common import cdiv, mxu_precision
 
 NEG_INF = -1e30
 
@@ -44,7 +43,8 @@ def _flash_kernel(
                 jnp.int32, (block_k,), 0)) < seq_len
             k = jnp.where(kv_valid[:, None], k, 0)
             v = jnp.where(kv_valid[:, None], v, 0)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                    precision=mxu_precision(q.dtype)) * sm_scale
 
         # mask: kv-tail padding + causal upper triangle
         k_idx = ki * block_k + jax.lax.broadcasted_iota(
@@ -65,8 +65,8 @@ def _flash_kernel(
         p = jnp.exp(s - m_new)                   # (BQ, BK)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
+            p, v, preferred_element_type=jnp.float32,
+            precision=mxu_precision(v.dtype))
         m_ref[...] = m_new
 
     if causal:
@@ -120,7 +120,7 @@ def flash_attention_single_head(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,14 +1,8 @@
 """Shared helpers for Pallas TPU kernels."""
 from __future__ import annotations
 
-import math
-
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams (<= 0.5) to CompilerParams (>= 0.6); resolve
-# whichever this jax ships so kernels work across the range.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams")
+import jax
+import jax.numpy as jnp
 
 
 def cdiv(a: int, b: int) -> int:
@@ -17,6 +11,13 @@ def cdiv(a: int, b: int) -> int:
 
 def round_up(a: int, b: int) -> int:
     return cdiv(a, b) * b
+
+
+def mxu_precision(dtype):
+    """MXU precision for a kernel dot: float32 operands get full-f32
+    passes (one bf16 pass misses f32 references by ~1e-3); narrower
+    operands take the native pass."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def lane_efficiency_2d(bm: int, bn: int, m: int, n: int) -> float:

@@ -24,4 +24,6 @@ def _benchmark() -> KernelBenchmark:
         default_input=space.DEFAULT_INPUT,
         inputs={"4096": space.DEFAULT_INPUT},
         make_args=_make_args, run=ops.run, ref=conv2d_ref,
+        default_config={"BY": 128, "BX": 256, "UNROLL_TAPS": 1,
+                        "FILTER_SMEM": 1, "DMA_DEPTH": 1},
     )

@@ -2,10 +2,11 @@
 
 Stencil with halo: BlockSpec tiling cannot express overlapping reads, so the
 input stays in HBM (``memory_space=ANY``) and each program DMAs its
-(BY + F - 1, BX + F - 1) halo tile into VMEM scratch explicitly
-(``pltpu.make_async_copy``) — the production TPU pattern for halo exchange.
-The F×F filter is unrolled statically into shifted multiply-accumulates on
-the VPU.
+(BY + F - 1, BX + F - 1) halo tile, rounded up to the (8, 128) tiling, into
+VMEM scratch explicitly (``pltpu.make_async_copy``) — the production TPU
+pattern for halo exchange.  The F×F filter sits in SMEM; its taps run as
+shifted multiply-accumulates on the VPU, unrolled (UNROLL_TAPS=1) or with
+the F filter rows in a loop (UNROLL_TAPS=0).
 """
 from __future__ import annotations
 
@@ -16,25 +17,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
-from repro.kernels.common import cdiv
+from repro.kernels.common import cdiv, round_up
 
 
 def _conv2d_kernel(
-    img_ref,    # (H + F - 1, W + F - 1) in HBM/ANY — pre-padded by wrapper
-    flt_ref,    # (F, F) in VMEM
+    img_ref,    # padded image in HBM/ANY — pre-padded by wrapper
+    flt_ref,    # (F, F) in SMEM
     out_ref,    # (BY, BX) block in VMEM
-    tile_ref,   # scratch: (BY + F - 1, BX + F - 1) VMEM
+    tile_ref,   # scratch: halo tile rounded up to the (8, 128) tiling
     sem,        # DMA semaphore
     *, by: int, bx: int, f: int, unroll_taps: bool,
 ):
     i, j = pl.program_id(0), pl.program_id(1)
-    halo = f - 1
+    th, tw = tile_ref.shape
+    # the DMA moves a tile-aligned window (offsets are multiples of BY/BX,
+    # extents rounded up); the wrapper pads the image so it stays in bounds
     copy = pltpu.make_async_copy(
-        img_ref.at[pl.ds(i * by, by + halo), pl.ds(j * bx, bx + halo)],
-        tile_ref,
-        sem,
-    )
+        img_ref.at[pl.ds(i * by, th), pl.ds(j * bx, tw)], tile_ref, sem)
     copy.start()
     copy.wait()
 
@@ -44,14 +43,14 @@ def _conv2d_kernel(
             for dx in range(f):
                 acc += flt_ref[dy, dx] * tile_ref[dy:dy + by, dx:dx + bx]
     else:
-        def tap(t, acc):
-            dy, dx = t // f, t % f
-            w = flt_ref[dy, dx]
-            patch = pl.load(
-                tile_ref, (pl.ds(dy, by), pl.ds(dx, bx))
-            )
-            return acc + w * patch
-        acc = jax.lax.fori_loop(0, f * f, tap, jnp.zeros((by, bx), jnp.float32))
+        # Mosaic slices sublanes only at multiples of 8, so a loop-carried
+        # row offset rotates the tile up by dy instead of slicing at dy
+        def row(dy, acc):
+            rows = pltpu.roll(tile_ref[...], th - dy, 0)[:by]
+            for dx in range(f):
+                acc += flt_ref[dy, dx] * rows[:, dx:dx + bx]
+            return acc
+        acc = jax.lax.fori_loop(0, f, row, jnp.zeros((by, bx), jnp.float32))
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
@@ -71,25 +70,27 @@ def conv2d(
     f = flt.shape[0]
     assert flt.shape == (f, f) and f % 2 == 1
     halo = f - 1
-    # pre-pad so every halo tile read is in bounds ("same" convolution)
-    img_p = jnp.pad(img, ((halo // 2, cdiv(h, by) * by - h + halo // 2),
-                          (halo // 2, cdiv(w, bx) * bx - w + halo // 2)))
-    grid = (cdiv(h, by), cdiv(w, bx))
+    th, tw = round_up(by + halo, 8), round_up(bx + halo, 128)
+    ny, nx = cdiv(h, by), cdiv(w, bx)
+    # "same" convolution: halo // 2 zeros in front; behind, enough that the
+    # last program's aligned (th, tw) window is in bounds
+    img_p = jnp.pad(img, ((halo // 2, (ny - 1) * by + th - h - halo // 2),
+                          (halo // 2, (nx - 1) * bx + tw - w - halo // 2)))
     return pl.pallas_call(
         functools.partial(_conv2d_kernel, by=by, bx=bx, f=f,
                           unroll_taps=unroll_taps),
-        grid=grid,
+        grid=(ny, nx),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),   # stays in HBM
-            pl.BlockSpec((f, f), lambda i, j: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),        # stays in HBM
+            pl.BlockSpec(memory_space=pltpu.SMEM),    # filter scalars
         ],
         out_specs=pl.BlockSpec((by, bx), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((h, w), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((by + halo, bx + halo), jnp.float32),
+            pltpu.VMEM((th, tw), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -29,4 +29,6 @@ def _benchmark() -> KernelBenchmark:
             "small_grid": space.SMALL_GRID,
         },
         make_args=_make_args, run=ops.run, ref=coulomb_ref,
+        default_config={"Z_IT": 4, "BY": 8, "BX": 128, "ATOM_CHUNK": 16,
+                        "ATOMS_IN_SMEM": 1},
     )

@@ -29,4 +29,6 @@ def _benchmark() -> KernelBenchmark:
             "4096x16": space.RECT_WIDE,
         },
         make_args=_make_args, run=ops.run, ref=matmul_ref,
+        default_config={"BLOCK_M": 256, "BLOCK_N": 256, "BLOCK_K": 512,
+                        "LOOP_ORDER": "mnk", "ACC_F32": 1},
     )

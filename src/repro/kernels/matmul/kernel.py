@@ -13,8 +13,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
-from repro.kernels.common import cdiv
+from repro.kernels.common import cdiv, mxu_precision
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int,
@@ -35,7 +34,8 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int,
         a = jnp.where(valid[None, :], a, 0)
         b = jnp.where(valid[:, None], b, 0)
 
-    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32,
+                            precision=mxu_precision(a.dtype))
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _done():
@@ -90,7 +90,7 @@ def matmul(
         out_specs=pl.BlockSpec((block_m, block_n), o_map),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

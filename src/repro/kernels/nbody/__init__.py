@@ -27,4 +27,6 @@ def _benchmark() -> KernelBenchmark:
             "131k": space.LARGE_INPUT,
         },
         make_args=_make_args, run=ops.run, ref=nbody_ref,
+        default_config={"BLOCK_I": 256, "BLOCK_J": 256, "J_UNROLL": 1,
+                        "KEEP_PAIRWISE": 0},
     )

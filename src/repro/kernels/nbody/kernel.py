@@ -15,39 +15,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
 from repro.kernels.common import cdiv
 
 
 def _nbody_kernel(
-    bi_ref, bj_ref, out_ref, acc_ref, *,
+    bi_ref, bjt_ref, out_ref, acc_ref, *,
     j_steps: int, n_bodies: int, block_j: int, softening: float,
 ):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bi = bi_ref[...]  # (BI, 4): x, y, z, m
+    bi = bi_ref[...]  # (BI, 4): x, y, z, m — one body per sublane row
+    # the j tile arrives transposed, (4, BJ): one body per lane, so every
+    # pairwise term is a (BI, 1) x (1, BJ) broadcast with no relayout
     j_idx = pl.program_id(1) * block_j + jax.lax.broadcasted_iota(
-        jnp.int32, (block_j,), 0
+        jnp.int32, (1, block_j), 1
     )
-    # zero the whole tail tile: padded rows hold undefined values (NaN in
+    # zero the whole tail tile: padded lanes hold undefined values (NaN in
     # interpret mode) and even mass-masked NaN positions would poison s*dx
-    bj = jnp.where((j_idx < n_bodies)[:, None], bj_ref[...], 0.0)
-    mj = bj[:, 3]
+    bjt = jnp.where(j_idx < n_bodies, bjt_ref[...], 0.0)
 
     # pairwise displacement: (BI, BJ)
-    dx = bj[None, :, 0] - bi[:, None, 0]
-    dy = bj[None, :, 1] - bi[:, None, 1]
-    dz = bj[None, :, 2] - bi[:, None, 2]
+    dx = bjt[0:1, :] - bi[:, 0:1]
+    dy = bjt[1:2, :] - bi[:, 1:2]
+    dz = bjt[2:3, :] - bi[:, 2:3]
     r2 = dx * dx + dy * dy + dz * dz + softening
     inv_r = jax.lax.rsqrt(r2)
-    s = mj[None, :] * inv_r * inv_r * inv_r  # (BI, BJ)
+    s = bjt[3:4, :] * inv_r * inv_r * inv_r  # (BI, BJ)
 
-    ax = jnp.sum(s * dx, axis=1)
-    ay = jnp.sum(s * dy, axis=1)
-    az = jnp.sum(s * dz, axis=1)
-    acc_ref[...] += jnp.stack([ax, ay, az, jnp.zeros_like(ax)], axis=1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
+    acc = jnp.zeros(acc_ref.shape, jnp.float32)
+    for c, d in enumerate((dx, dy, dz)):
+        acc = jnp.where(lane == c, jnp.sum(s * d, axis=1, keepdims=True), acc)
+    acc_ref[...] += acc
 
     @pl.when(pl.program_id(1) == j_steps - 1)
     def _done():
@@ -77,13 +78,13 @@ def nbody(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_i, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_j, 4), lambda i, j: (j, 0)),
+            pl.BlockSpec((4, block_j), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_i, 4), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 4), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_i, 4), jnp.float32)],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(bodies, bodies)
+    )(bodies, bodies.T)

@@ -35,6 +35,8 @@ class KernelBenchmark:
     make_args: Callable[[Any, Any], Tuple]
     run: Callable[..., Any]       # run(cfg, *args, interpret=...)
     ref: Callable[..., Any]       # ref(*args)
+    # a configuration the TPU v5e compiler accepts at ``default_input``
+    default_config: Config
     _space: TuningSpace = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
 

@@ -22,4 +22,5 @@ def _benchmark() -> KernelBenchmark:
         default_input=space.DEFAULT_INPUT,
         inputs={"8192": space.DEFAULT_INPUT},
         make_args=_make_args, run=ops.run, ref=transpose_ref,
+        default_config={"BLOCK_M": 256, "BLOCK_N": 256, "STAGE_OUT": 0},
     )

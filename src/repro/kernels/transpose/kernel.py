@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import common
 from repro.kernels.common import cdiv
 
 
@@ -40,7 +39,7 @@ def transpose(
         in_specs=[pl.BlockSpec((block_m, block_n), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((block_n, block_m), lambda i, j: (j, i)),
         out_shape=jax.ShapeDtypeStruct((n, m), x.dtype),
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -32,7 +32,6 @@ def main(argv=None) -> int:
     ap.add_argument("--backend", default="thread",
                     choices=("virtual", "thread", "subprocess"))
     ap.add_argument("--workers", type=int, default=4)
-    ap.add_argument("--devices-per-worker", type=int, default=0)
     ap.add_argument("--store-dir", default=None,
                     help="sharded corpus directory (the default)")
     ap.add_argument("--store", default=None,
@@ -102,7 +101,7 @@ def main(argv=None) -> int:
             mode=args.fsync)
     if args.recover and journal is None:
         ap.error("--recover requires a journal (drop --no-journal)")
-    pool = build_pool(args.backend, args.workers, args.devices_per_worker)
+    pool = build_pool(args.backend, args.workers)
     gc_keep = None
     if args.gc_keep_hardware:
         gc_keep = {"keep_hardware": [h.strip() for h in

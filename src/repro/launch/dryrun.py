@@ -1,44 +1,38 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST precede any other import (jax locks the device
-count at first init).  Run as
+``main`` gives the CPU backend 512 devices before JAX initialises (the
+device count locks at first init), so run it on the host:
 
-    PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-3b \
-        --shape train_4k --mesh single --out results.jsonl
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun \
+        --arch qwen2.5-3b --shape train_4k --mesh single --out results.jsonl
 
 or with --all to sweep every live cell sequentially.  Each cell prints
 ``memory_analysis()`` (proof it fits) and ``cost_analysis()`` FLOPs/bytes
 (roofline inputs), and appends a JSON record.
 """
-import argparse      # noqa: E402
-import json          # noqa: E402
-import sys           # noqa: E402
-import time          # noqa: E402
-import traceback     # noqa: E402
+import argparse
+import json
+import sys
+import time
+import traceback
 
-import jax           # noqa: E402
-import jax.numpy as jnp  # noqa: E402
+import jax
+import jax.numpy as jnp
 
-from repro.configs import ARCHS                                   # noqa: E402
-from repro.distributed.api import activation_sharding             # noqa: E402
-from repro.distributed.sharding import (batch_shardings,          # noqa: E402
-                                        cache_shardings,
-                                        default_rules,
-                                        make_act_resolver,
-                                        param_shardings)
-from repro.launch.mesh import make_production_mesh                # noqa: E402
-from repro.models.config import SHAPES, shape_applicable          # noqa: E402
-from repro.models.registry import build_model                     # noqa: E402
-from repro.optim.adamw import AdamW, warmup_cosine                # noqa: E402
-from repro.roofline import analysis as roofline                   # noqa: E402
-from repro.train.train_step import (StepConfig,                   # noqa: E402
-                                    abstract_train_state,
+from repro.configs import ARCHS
+from repro.distributed.api import activation_sharding
+from repro.distributed.sharding import (batch_shardings, cache_shardings,
+                                        default_rules, make_act_resolver,
+                                        param_shardings,
+                                        train_state_shardings)
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_production_mesh, use_host_devices
+from repro.models.config import SHAPES, shape_applicable
+from repro.models.registry import build_model
+from repro.optim.adamw import AdamW, warmup_cosine
+from repro.roofline import analysis as roofline
+from repro.train.train_step import (StepConfig, abstract_train_state,
                                     make_train_step)
-
-from jax.sharding import NamedSharding, PartitionSpec as P        # noqa: E402
 
 
 # Per-(arch, shape) step-config overrides: microbatches bound the live
@@ -85,19 +79,8 @@ def lower_cell(arch_name: str, shape_name: str, multi_pod: bool,
                 optimizer = AdamW(lr=warmup_cosine(3e-4, 2000, 100000))
                 step = make_train_step(model, optimizer, scfg)
                 state_abs = abstract_train_state(model, optimizer)
-                state_sh = jax.tree.map(
-                    lambda _: None, state_abs,
-                    is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
-                # params/opt follow logical specs; step counter replicated
-                specs = model.specs()
-                p_sh = param_shardings(mesh, rules, specs, state_abs.params)
-                m_sh = param_shardings(mesh, rules, specs, state_abs.opt.m)
-                v_sh = param_shardings(mesh, rules, specs, state_abs.opt.v)
-                rep = NamedSharding(mesh, P())
-                state_sh = type(state_abs)(
-                    params=p_sh,
-                    opt=type(state_abs.opt)(m=m_sh, v=v_sh, count=rep),
-                    step=rep)
+                state_sh = train_state_shardings(mesh, rules, model.specs(),
+                                                 state_abs)
                 batch_abs = model.input_specs(shape)
                 b_sh = batch_shardings(mesh, rules, batch_abs)
                 lowered = jax.jit(
@@ -186,7 +169,8 @@ def lower_cell(arch_name: str, shape_name: str, multi_pod: bool,
     return rec
 
 
-def main():
+def main(argv=None):
+    use_host_devices(512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None,
@@ -195,7 +179,8 @@ def main():
                     choices=("single", "multi", "both"))
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--out", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cells = []
     meshes = {"single": [False], "multi": [True],

@@ -10,9 +10,9 @@ artifacts into a shared ``ConfigStore`` — so re-running with more hardware
         --kernels matmul,transpose --hw tpu_v4,tpu_v5e \
         --store fleet_store.json --workers 4 --budget 25
 
-    # subprocess lanes, each with its own 2-device jax host runtime
+    # subprocess lanes, one evaluation process each
     PYTHONPATH=src python -m repro.launch.fleet --backend subprocess \
-        --workers 2 --devices-per-worker 2 --kernels matmul --hw tpu_v5e
+        --workers 2 --kernels matmul --hw tpu_v5e
 
     # whole-system mode: kernel tiles + train-step sharding + serve
     # geometry for one model-zoo entry, one fleet, one store
@@ -30,7 +30,7 @@ import json
 import time
 
 
-def build_pool(backend: str, workers: int, devices_per_worker: int):
+def build_pool(backend: str, workers: int):
     from repro.fleet import (SubprocessWorkerPool, ThreadWorkerPool,
                              VirtualWorkerPool)
 
@@ -39,8 +39,7 @@ def build_pool(backend: str, workers: int, devices_per_worker: int):
     if backend == "thread":
         return ThreadWorkerPool(workers=workers)
     if backend == "subprocess":
-        return SubprocessWorkerPool(workers=workers,
-                                    devices_per_worker=devices_per_worker)
+        return SubprocessWorkerPool(workers=workers)
     raise ValueError(f"unknown backend {backend!r}")
 
 
@@ -67,8 +66,6 @@ def main(argv=None) -> int:
     ap.add_argument("--backend", default="virtual",
                     choices=("virtual", "thread", "subprocess"))
     ap.add_argument("--workers", type=int, default=4)
-    ap.add_argument("--devices-per-worker", type=int, default=0,
-                    help="subprocess backend: jax host devices per worker")
     ap.add_argument("--in-flight", type=int, default=None,
                     help="outstanding tests pool-wide (default: --workers)")
     ap.add_argument("--in-flight-max", type=int, default=None,
@@ -159,7 +156,7 @@ def main(argv=None) -> int:
                                   seed=args.seed, searcher=args.searcher)
                 for k, inp in zip(kernels, inputs) for hw in hws]
     store = ConfigStore(args.store)
-    pool = build_pool(args.backend, args.workers, args.devices_per_worker)
+    pool = build_pool(args.backend, args.workers)
     t0 = time.time()
     tuner = FleetTuner(jobs, pool, store=store,
                        in_flight=args.in_flight,

@@ -19,11 +19,15 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, SMOKES
+from repro.core.hwspec import spec_for_device
+from repro.launch.cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.serve.engine import Request, ServeEngine, tune_engine_batch
 
 
-def main():
+def run(argv=None):
+    """Parse the CLI and serve; return the requests, their generated
+    tokens and, with ``--autotune``, the tuner and its tick report."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--smoke", action="store_true")
@@ -49,7 +53,8 @@ def main():
                          "service and fall back in-process when it is "
                          "unreachable (start one with "
                          "python -m repro.launch.daemon)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = (SMOKES if args.smoke else ARCHS)[args.arch]
     model = build_model(arch)
@@ -66,6 +71,8 @@ def main():
                                           stats_from_model)
         from repro.tuning.store import ConfigStore
 
+        # tuned configs are filed under the chip they were measured on
+        hw = spec_for_device(jax.devices()[0])
         backend = EngineBackend(model, rng=jax.random.PRNGKey(0))
         tuner = OnlineAutotuner(
             backend,
@@ -76,7 +83,7 @@ def main():
                 {args.max_seq, args.max_seq // 2, 2 * args.max_seq}))),
             stats=stats_from_model(model),
             max_live_trials=args.live_trials,
-            hardware_name=jax.default_backend(),
+            hw=hw,
             service=args.service,
         )
         t0 = time.time()
@@ -91,7 +98,8 @@ def main():
                   f"(trials={rep.live_trials}) -> {rep.config}")
         print(f"[serve] {len(reqs)} requests, {n} tokens in {dt:.1f}s "
               f"({n/max(dt, 1e-9):.1f} tok/s)")
-        return 0
+        return {"requests": reqs, "outputs": out, "report": rep,
+                "tuner": tuner}
 
     batch = args.batch
     if args.tune_batch:
@@ -109,6 +117,11 @@ def main():
     n = sum(len(v) for v in out.values())
     print(f"[serve] {len(reqs)} requests, {n} tokens in {dt:.1f}s "
           f"({n/dt:.1f} tok/s)")
+    return {"requests": reqs, "outputs": out, "report": None, "tuner": None}
+
+
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 

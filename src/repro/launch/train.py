@@ -3,7 +3,8 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \
         --steps 50 --batch 8 --seq 256 --smoke --ckpt-dir /tmp/ckpt
 
-Runs on whatever devices exist (CPU smoke → full pod; the mesh adapts).
+``--data-model`` must cover every device present (CPU smoke → one chip →
+a 2x2 host), and the run refuses a mesh that would leave chips idle.
 Fault tolerance: resumes from the latest complete checkpoint; a per-step
 watchdog aborts wedged steps so the supervisor (launch/supervisor.py or any
 process manager) can re-exec the job, which then restores and continues —
@@ -17,21 +18,20 @@ import threading
 import time
 
 import jax
-import numpy as np
 
 from repro.configs import ARCHS, SMOKES
 from repro.data.pipeline import DataConfig, make_batch
 from repro.distributed.api import activation_sharding
 from repro.distributed.sharding import (batch_shardings, default_rules,
-                                        make_act_resolver, param_shardings)
+                                        make_act_resolver,
+                                        train_state_shardings)
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model
 from repro.optim.adamw import AdamW, warmup_cosine
-from repro.train.train_step import (StepConfig, TrainState, init_train_state,
-                                    make_train_step)
+from repro.train.train_step import (StepConfig, abstract_train_state,
+                                    init_train_state, make_train_step)
 from repro.checkpoint.checkpointer import Checkpointer
-
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 class StepWatchdog:
@@ -64,7 +64,79 @@ class StepWatchdog:
         os._exit(42)
 
 
-def main():
+def train(arch, mesh, *, steps: int, batch: int, seq: int, lr: float = 3e-4,
+          microbatches: int = 1, remat: str = "nothing_saveable",
+          step_timeout: float = 600.0, ckpt_dir=None, ckpt_every: int = 10):
+    """Train ``arch`` on ``mesh`` from a seeded init (or the latest
+    checkpoint in ``ckpt_dir``); return each step's loss and grad norm.
+
+    Params and Adam moments are created already sharded (one jitted init
+    with the state's shardings as its output), so no device ever holds the
+    whole state."""
+    model = build_model(arch)
+    rules = default_rules(multi_pod=False)
+    optimizer = AdamW(lr=warmup_cosine(lr, max(steps // 10, 1), steps))
+    scfg = StepConfig(remat=remat, microbatches=microbatches, loss_chunks=1)
+    step_fn = make_train_step(model, optimizer, scfg)
+
+    dcfg = DataConfig(
+        vocab_size=arch.vocab_size, seq_len=seq, global_batch=batch,
+        frontend=arch.frontend, frontend_len=arch.frontend_len,
+        frontend_dim=arch.frontend_dim,
+    )
+
+    resolver = make_act_resolver(mesh, rules)
+    ckpt = Checkpointer(ckpt_dir) if ckpt_dir else None
+    watchdog = StepWatchdog(step_timeout)
+    history = []
+
+    with mesh:
+        with activation_sharding(resolver):
+            state_abs = abstract_train_state(model, optimizer)
+            state_sh = train_state_shardings(mesh, rules, model.specs(),
+                                             state_abs)
+            state = jax.jit(
+                lambda key: init_train_state(model, optimizer, key),
+                out_shardings=state_sh)(jax.random.PRNGKey(0))
+            start = 0
+            if ckpt is not None:
+                got = ckpt.restore_latest(state, state_sh)
+                if got[0] is not None:
+                    start, state = got
+                    print(f"[train] restored checkpoint at step {start}")
+
+            b_sh = batch_shardings(mesh, rules, {
+                k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+                for k, v in make_batch(dcfg, 0).items()})
+            jit_step = jax.jit(step_fn, in_shardings=(state_sh, b_sh),
+                               out_shardings=(state_sh, None),
+                               donate_argnums=(0,))
+            t0 = time.time()
+            for step in range(start, steps):
+                batch_ = jax.device_put(make_batch(dcfg, step), b_sh)
+                watchdog.arm()
+                state, metrics = jit_step(state, batch_)
+                loss = float(metrics["loss"])
+                watchdog.disarm()
+                gnorm = float(metrics["grad_norm"])
+                history.append({"step": step, "loss": loss,
+                                "grad_norm": gnorm})
+                if step % 5 == 0 or step == steps - 1:
+                    dt = time.time() - t0
+                    print(f"[train] step {step:5d} loss {loss:.4f} "
+                          f"gnorm {gnorm:.3f} ({dt:.1f}s)")
+                if ckpt is not None and (step + 1) % ckpt_every == 0:
+                    ckpt.save(step + 1, state)
+            if ckpt is not None:
+                ckpt.save(steps, state)
+                ckpt.wait()
+            if history:
+                print(f"[train] done: final loss {history[-1]['loss']:.4f}")
+    return history
+
+
+def run(argv=None):
+    """Parse the CLI, train, and return the per-step history."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--steps", type=int, default=20)
@@ -79,65 +151,20 @@ def main():
     ap.add_argument("--remat", default="nothing_saveable")
     ap.add_argument("--step-timeout", type=float, default=600.0)
     ap.add_argument("--data-model", type=int, nargs=2, default=(1, 1),
-                    help="mesh shape (data, model)")
-    args = ap.parse_args()
+                    help="mesh shape (data, model); must use every device")
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = (SMOKES if args.smoke else ARCHS)[args.arch]
-    model = build_model(arch)
-    mesh = make_host_mesh(*args.data_model)
-    rules = default_rules(multi_pod=False)
-    optimizer = AdamW(lr=warmup_cosine(args.lr, max(args.steps // 10, 1),
-                                       args.steps))
-    scfg = StepConfig(remat=args.remat, microbatches=args.microbatches,
-                      loss_chunks=1)
-    step_fn = make_train_step(model, optimizer, scfg)
+    return train(arch, make_host_mesh(*args.data_model), steps=args.steps,
+                 batch=args.batch, seq=args.seq, lr=args.lr,
+                 microbatches=args.microbatches, remat=args.remat,
+                 step_timeout=args.step_timeout, ckpt_dir=args.ckpt_dir,
+                 ckpt_every=args.ckpt_every)
 
-    dcfg = DataConfig(
-        vocab_size=arch.vocab_size, seq_len=args.seq,
-        global_batch=args.batch,
-        frontend=arch.frontend, frontend_len=arch.frontend_len,
-        frontend_dim=arch.frontend_dim,
-    )
 
-    resolver = make_act_resolver(mesh, rules)
-    ckpt = Checkpointer(args.ckpt_dir) if args.ckpt_dir else None
-    watchdog = StepWatchdog(args.step_timeout)
-
-    with mesh:
-        with activation_sharding(resolver):
-            state = init_train_state(model, optimizer, jax.random.PRNGKey(0))
-            specs = model.specs()
-            p_sh = param_shardings(mesh, rules, specs, state.params)
-            state = TrainState(
-                params=jax.tree.map(jax.device_put, state.params, p_sh),
-                opt=state.opt, step=state.step)
-            start = 0
-            if ckpt is not None:
-                got = ckpt.restore_latest(state)
-                if got[0] is not None:
-                    start, state = got
-                    print(f"[train] restored checkpoint at step {start}")
-
-            jit_step = jax.jit(step_fn, donate_argnums=(0,))
-            t0 = time.time()
-            for step in range(start, args.steps):
-                batch = {k: jax.device_put(v)
-                         for k, v in make_batch(dcfg, step).items()}
-                watchdog.arm()
-                state, metrics = jit_step(state, batch)
-                loss = float(metrics["loss"])
-                watchdog.disarm()
-                if step % 5 == 0 or step == args.steps - 1:
-                    dt = time.time() - t0
-                    print(f"[train] step {step:5d} loss {loss:.4f} "
-                          f"gnorm {float(metrics['grad_norm']):.3f} "
-                          f"({dt:.1f}s)")
-                if ckpt is not None and (step + 1) % args.ckpt_every == 0:
-                    ckpt.save(step + 1, state)
-            if ckpt is not None:
-                ckpt.save(args.steps, state)
-                ckpt.wait()
-            print(f"[train] done: final loss {loss:.4f}")
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 

@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """Step-config tuning driver over the public ``repro.tuning`` API.
 
 Tunes the distributed train-step configuration (microbatches, remat, loss
@@ -11,15 +8,21 @@ with the paper's two-phase flow made operational:
   load + tune:    --load-model step_tppc.json  (skip the training compiles —
                   the artifact may come from a DIFFERENT machine)
 
-    PYTHONPATH=src python -m repro.launch.tune --arch qwen2.5-3b \
-        [--searcher profile] [--budget 10] [--save-model step_tppc.json]
-"""
-import argparse      # noqa: E402
-import json          # noqa: E402
-import time          # noqa: E402
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.tune \
+        --arch qwen2.5-3b [--searcher profile] [--budget 10] \
+        [--save-model step_tppc.json]
 
-from repro.core.step_tuner import CompiledStepEvaluator  # noqa: E402
-from repro.tuning import SEARCHERS, TuningSession        # noqa: E402
+``main`` gives the CPU backend 512 devices before JAX initialises: the
+step compiles target the 256-chip production mesh.
+"""
+import argparse
+import json
+import time
+
+from repro.core.step_tuner import CompiledStepEvaluator
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import use_host_devices
+from repro.tuning import SEARCHERS, TuningSession
 
 
 def _tune_problem(args) -> int:
@@ -68,7 +71,8 @@ def _tune_problem(args) -> int:
     return 0
 
 
-def main():
+def main(argv=None):
+    use_host_devices(512)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--shape", default="train_4k")
@@ -105,7 +109,8 @@ def main():
     ap.add_argument("--load-model", default=None)
     ap.add_argument("--out", default=None)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.problem:
         # fleet-auto searcher when unset: warm_start on store hit, else cold

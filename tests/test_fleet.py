@@ -472,8 +472,8 @@ def test_online_autotuner_in_flight_matches_sequential(tmp_path):
 # =============================================================================
 @pytest.mark.slow
 def test_subprocess_pool_matches_virtual():
-    """2 worker processes, each with a 2-device jax host runtime, agree
-    with the in-process virtual backend on what they measured."""
+    """2 worker processes agree with the in-process virtual backend on
+    what they measured."""
     from repro.fleet import SubprocessWorkerPool
 
     def jobs():
@@ -481,7 +481,7 @@ def test_subprocess_pool_matches_virtual():
                                   searcher="random")
                 for hw in ("tpu_v4", "tpu_v5e")]
 
-    pool = SubprocessWorkerPool(workers=2, devices_per_worker=2)
+    pool = SubprocessWorkerPool(workers=2)
     try:
         rep_sub = FleetTuner(jobs(), pool, store=None,
                              publish_models=False).run()

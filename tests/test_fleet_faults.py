@@ -423,7 +423,7 @@ def test_subprocess_lane_death_drains_before_fleet_dead():
     from repro.fleet import SubprocessWorkerPool
 
     ok = {"kernel": "matmul", "input": "128", "hw": "tpu_v4"}
-    pool = SubprocessWorkerPool(workers=2, devices_per_worker=0)
+    pool = SubprocessWorkerPool(workers=2)
     try:
         pool.submit(WorkItem(uid=1, job="j", index=0, payload=dict(ok)))
         res1 = pool.collect(timeout=120)

@@ -1,6 +1,7 @@
 """Sharding rules, HLO cost parser, and multi-device integration
 (the 512-device dry-run path is covered by launch/dryrun.py; here we check
 the machinery on small in-process examples + an 8-device subprocess)."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -83,8 +84,9 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     from repro.distributed.sharding import default_rules, param_shardings
     from repro.distributed.api import activation_sharding
     from repro.distributed.sharding import make_act_resolver
+    from repro.launch.mesh import make_host_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(4, 2)
     rules = default_rules(multi_pod=False)
     model = build_model(SMOKES["qwen2.5-3b"])
     opt = AdamW(lr=constant_lr(1e-3))
@@ -110,11 +112,12 @@ _SUBPROCESS_PROG = textwrap.dedent("""
 def test_multidevice_train_step_subprocess():
     """Real 8-device SPMD execution (numerics, not just compile) — by far
     the suite's single slowest test (minutes of subprocess XLA compiles)."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_PROG],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"},
-        cwd="/root/repo",
+             "HOME": os.environ.get("HOME", repo), "JAX_PLATFORMS": "cpu"},
+        cwd=repo,
     )
     assert "MULTIDEV_OK" in r.stdout, r.stdout + r.stderr
