@@ -51,25 +51,13 @@ def check(cond, msg):
         raise PhaseFailed(msg)
 
 
-class CompileClock:
-    """Sums XLA backend-compile seconds and persistent-cache hits from
-    JAX's monitoring events."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
+def compiled_since(before: dict, after: dict):
+    """Backend-compile seconds between two ``obs.snapshot()``s, and the
+    three functions that took most of them."""
+    secs = {f: s - before["compile.seconds"].get(f, 0.0)
+            for f, s in after["compile.seconds"].items()}
+    top = sorted(secs.items(), key=lambda kv: -kv[1])[:3]
+    return sum(secs.values()), [(f, round(s, 1)) for f, s in top if s > 0]
 
 
 def rel_err(out, ref):
@@ -265,27 +253,33 @@ def main(argv=None) -> int:
 
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), "src"))
+    from repro import obs
     from repro.launch.cache import enable_compile_cache
 
     cache_dir = enable_compile_cache()
-    clock = CompileClock()
+    first = obs.snapshot()
     print(f"[device] {dev.platform} {dev.device_kind} x{len(devices)}; "
           f"compile cache {cache_dir}", flush=True)
 
     phases = ([phase_sharded] if args.chips == 4 else
               [phase_kernels, phase_tune, phase_train, phase_serve])
     for phase in phases:
-        t0, c0 = time.perf_counter(), clock.seconds
+        t0, before = time.perf_counter(), obs.snapshot()
         try:
             phase()
         except PhaseFailed as e:
             print(f"[{phase.__name__[6:]}] FAIL: {e}", file=sys.stderr)
             return 1
+        secs, top = compiled_since(before, obs.snapshot())
         print(f"[{phase.__name__[6:]}] {time.perf_counter() - t0:.1f}s wall, "
-              f"{clock.seconds - c0:.1f}s backend compile", flush=True)
+              f"{secs:.1f}s backend compile; most: {top}", flush=True)
+    last = obs.snapshot()
+    secs, _ = compiled_since(first, last)
+    hits = last.get("compile.cache_hits", 0) - first.get(
+        "compile.cache_hits", 0)
     print(f"[total] {time.perf_counter() - t_start:.1f}s wall, "
-          f"{clock.seconds:.1f}s backend compile, {clock.hits} persistent "
-          "cache hits", flush=True)
+          f"{secs:.1f}s backend compile, {hits} persistent cache hits",
+          flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

@@ -38,6 +38,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import costmodel
 from repro.core import counters as C
 from repro.core.evaluate import FunctionEvaluator
@@ -376,9 +377,10 @@ class EngineBackend:
         engine = self._engine(int(cfg["BATCH"]), int(cfg["MAX_SEQ"]),
                               n_requests=len(requests))
         reqs = [dataclasses.replace(r, generated=None) for r in requests]
-        t0 = time.perf_counter()
-        engine.generate(reqs)
-        return time.perf_counter() - t0
+        with obs.span("tuner.trial"):
+            t0 = time.perf_counter()
+            engine.generate(reqs)
+            return time.perf_counter() - t0
 
     def serve(self, cfg: Config, requests: Sequence[Request]
               ) -> Dict[int, List[int]]:
@@ -652,25 +654,28 @@ class OnlineAutotuner:
                                self.hardware_name, kind="serve")
         if entry is not None:
             return entry, 0, True
-        entry = self._tune_via_service(bucket)
-        if entry is not None:
-            self._via_service = True
-            return entry, 0, False
-        _, calib_plen, calib_new = _tick_shape(calib)
-        order = self.ranking(bucket, min_seq=calib_plen + calib_new)
-        ev = FunctionEvaluator(
-            self.space, lambda cfg: self.backend.measure(cfg, calib))
-        searcher = WarmStartSearcher(self.space, order=order, seed=self.seed)
-        run_search(searcher, ev, min(self.max_live_trials, len(order)),
-                   in_flight=self.in_flight)
-        plen, new = self.bucketer.rep_shape(bucket)
-        entry = self.store.put(
-            self.space.name, bucket.key, self.hardware_name,
-            config=self.space[ev.best_index],
-            runtime=ev.best_runtime, trials=ev.steps,
-            meta={"history": [[int(i), float(rt)] for i, rt in ev.history()],
-                  "bucket_shape": [plen, new]},
-            kind="serve")
+        with obs.span("tuner.retune"):
+            entry = self._tune_via_service(bucket)
+            if entry is not None:
+                self._via_service = True
+                return entry, 0, False
+            _, calib_plen, calib_new = _tick_shape(calib)
+            order = self.ranking(bucket, min_seq=calib_plen + calib_new)
+            ev = FunctionEvaluator(
+                self.space, lambda cfg: self.backend.measure(cfg, calib))
+            searcher = WarmStartSearcher(self.space, order=order,
+                                         seed=self.seed)
+            run_search(searcher, ev, min(self.max_live_trials, len(order)),
+                       in_flight=self.in_flight)
+            plen, new = self.bucketer.rep_shape(bucket)
+            entry = self.store.put(
+                self.space.name, bucket.key, self.hardware_name,
+                config=self.space[ev.best_index],
+                runtime=ev.best_runtime, trials=ev.steps,
+                meta={"history": [[int(i), float(rt)]
+                                  for i, rt in ev.history()],
+                      "bucket_shape": [plen, new]},
+                kind="serve")
         return entry, ev.steps, False
 
     # -- the serving loop ------------------------------------------------------
@@ -687,16 +692,17 @@ class OnlineAutotuner:
         dom = self._seen[dom_key]
         drift = self._active is None or self._active.bucket != dom_key
         live, reused, history = 0, False, []
-        if drift:
-            calib = [r for r, b in zip(requests, buckets)
-                     if b.key == dom_key][: self.calib_n]
-            if not calib:
-                calib = list(requests)[: self.calib_n]
-            entry, live, reused = self.ensure(dom, calib)
-            history = [tuple(h) for h in entry.meta.get("history", [])] \
-                if not reused else []
-            self._active = entry
-        outputs = self.backend.serve(self._active.config, requests)
+        with obs.span("tuner.tick", counts=True):
+            if drift:
+                calib = [r for r, b in zip(requests, buckets)
+                         if b.key == dom_key][: self.calib_n]
+                if not calib:
+                    calib = list(requests)[: self.calib_n]
+                entry, live, reused = self.ensure(dom, calib)
+                history = [tuple(h) for h in entry.meta.get("history", [])] \
+                    if not reused else []
+                self._active = entry
+            outputs = self.backend.serve(self._active.config, requests)
         report = TickReport(bucket=dom_key, drift=drift, reused=reused,
                             live_trials=live, config=dict(self._active.config),
                             history=history,

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.registry import Model
 
 
@@ -58,33 +59,42 @@ class ServeEngine:
         # count — pure wasted compute that also skews wave timings.
         n = len(wave)
         plen = max(len(r.prompt) for r in wave)
-        toks = np.zeros((n, plen), np.int32)
-        for i, r in enumerate(wave):
-            toks[i, plen - len(r.prompt):] = r.prompt  # left-pad
-        batch = {"tokens": jnp.asarray(toks)}
-        logits, cache = self.model.prefill(self.params, batch,
-                                           max_seq=self.max_seq)
-        next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        steps = max(r.max_new_tokens for r in wave)
-        done = np.zeros(n, bool)
-        gen: List[List[int]] = [[] for _ in range(n)]
-        # an exhausted budget means no generated tokens at all — enforce the
-        # limit before the first append, not after it
-        for i, r in enumerate(wave):
-            if r.max_new_tokens <= 0:
-                done[i] = True
-        for _ in range(steps):
+        with obs.span("engine.wave", uids=[r.uid for r in wave]):
+            with obs.span("engine.prefill"):
+                toks = np.zeros((n, plen), np.int32)
+                for i, r in enumerate(wave):
+                    toks[i, plen - len(r.prompt):] = r.prompt  # left-pad
+                batch = {"tokens": jnp.asarray(toks)}
+                logits, cache = self.model.prefill(self.params, batch,
+                                                   max_seq=self.max_seq)
+                next_tok = jnp.argmax(logits[:, -1],
+                                      axis=-1).astype(jnp.int32)
+            steps = max(r.max_new_tokens for r in wave)
+            done = np.zeros(n, bool)
+            gen: List[List[int]] = [[] for _ in range(n)]
+            # an exhausted budget means no generated tokens at all — enforce
+            # the limit before the first append, not after it
             for i, r in enumerate(wave):
-                if not done[i]:
-                    gen[i].append(int(next_tok[i]))
-                    if (int(next_tok[i]) == r.eos_id
-                            or len(gen[i]) >= r.max_new_tokens):
-                        done[i] = True
-            if done.all():
-                break
-            logits, cache = self._decode(self.params, cache,
-                                         {"tokens": next_tok[:, None]})
-            next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                if r.max_new_tokens <= 0:
+                    done[i] = True
+            for _ in range(steps):
+                with obs.span("engine.step"):
+                    # each live row reads its token from the device twice
+                    obs.add("engine.host_pulls", 2 * int(n - done.sum()))
+                    with obs.span("engine.pull"):
+                        for i, r in enumerate(wave):
+                            if not done[i]:
+                                gen[i].append(int(next_tok[i]))
+                                if (int(next_tok[i]) == r.eos_id
+                                        or len(gen[i]) >= r.max_new_tokens):
+                                    done[i] = True
+                    if done.all():
+                        break
+                    logits, cache = self._decode(
+                        self.params, cache, {"tokens": next_tok[:, None]})
+                    obs.add("engine.decode_steps")
+                    next_tok = jnp.argmax(logits[:, -1],
+                                          axis=-1).astype(jnp.int32)
         return {r.uid: gen[i] for i, r in enumerate(wave)}
 
     def warmup(self, prompt_len: int = 4, wave_size: Optional[int] = None
