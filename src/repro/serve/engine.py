@@ -10,6 +10,14 @@ For simplicity slots share one right-aligned cache (prefill fills positions
 [0, prompt_len); decode appends) and admission happens between decode
 steps.  This is the serving analog of the train driver and the substrate
 for the decode dry-run cells.
+
+A pass of the decode loop makes one device-to-host transfer: the (n, 1)
+vector of tokens the jitted decode picked.  Where the next decode is needed
+whatever those tokens say, it is dispatched before the read, so the device
+decodes while the host waits for and handles the pass.  Counters
+(``repro.obs``): ``engine.decode_steps``, one a decode dispatched;
+``engine.host_pulls``, one a pass (its transfer); ``engine.decode_ahead``,
+one a decode dispatched before its pass's read.
 """
 from __future__ import annotations
 
@@ -41,7 +49,15 @@ class ServeEngine:
         self.max_seq = max_seq
         self.params = params if params is not None else model.init(
             rng if rng is not None else jax.random.PRNGKey(0))
-        self._decode = jax.jit(model.decode, donate_argnums=(1,))
+
+        # the greedy pick runs inside the program (still ``jit_decode``), so a
+        # pass hands the host one (n, 1) vector that also feeds the next call
+        def decode(params, cache, batch):
+            logits, cache = model.decode(params, cache, batch)
+            return (jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32),
+                    cache)
+
+        self._decode = jax.jit(decode, donate_argnums=(1,))
 
     def generate(self, requests: List[Request]) -> Dict[int, List[int]]:
         """Run all requests to completion, batch_size at a time."""
@@ -67,8 +83,7 @@ class ServeEngine:
                 batch = {"tokens": jnp.asarray(toks)}
                 logits, cache = self.model.prefill(self.params, batch,
                                                    max_seq=self.max_seq)
-                next_tok = jnp.argmax(logits[:, -1],
-                                      axis=-1).astype(jnp.int32)
+                tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
             steps = max(r.max_new_tokens for r in wave)
             done = np.zeros(n, bool)
             gen: List[List[int]] = [[] for _ in range(n)]
@@ -79,23 +94,40 @@ class ServeEngine:
                     done[i] = True
             for _ in range(steps):
                 with obs.span("engine.step"):
-                    # each live row reads its token from the device twice
-                    obs.add("engine.host_pulls", 2 * int(n - done.sum()))
+                    # a live row that cannot stop on EOS and still has budget
+                    # after this pass needs the next decode whatever the
+                    # tokens say: dispatch it before the read, so the device
+                    # runs it while the host reads and handles this pass
+                    ahead = any(not done[i] and r.eos_id < 0
+                                and len(gen[i]) + 1 < r.max_new_tokens
+                                for i, r in enumerate(wave))
+                    if ahead:
+                        nxt = self._step(cache, tok)
+                    # moved every pass, by 0 or 1, so the counter exists
+                    # wherever this loop ran
+                    obs.add("engine.decode_ahead", int(ahead))
+                    obs.add("engine.host_pulls")
                     with obs.span("engine.pull"):
-                        for i, r in enumerate(wave):
-                            if not done[i]:
-                                gen[i].append(int(next_tok[i]))
-                                if (int(next_tok[i]) == r.eos_id
-                                        or len(gen[i]) >= r.max_new_tokens):
-                                    done[i] = True
-                    if done.all():
+                        picked = jax.device_get(tok)[:, 0].tolist()
+                    for i, r in enumerate(wave):
+                        if not done[i]:
+                            gen[i].append(picked[i])
+                            if (picked[i] == r.eos_id
+                                    or len(gen[i]) >= r.max_new_tokens):
+                                done[i] = True
+                    if ahead:
+                        tok, cache = nxt
+                    elif done.all():
                         break
-                    logits, cache = self._decode(
-                        self.params, cache, {"tokens": next_tok[:, None]})
-                    obs.add("engine.decode_steps")
-                    next_tok = jnp.argmax(logits[:, -1],
-                                          axis=-1).astype(jnp.int32)
+                    else:
+                        tok, cache = self._step(cache, tok)
         return {r.uid: gen[i] for i, r in enumerate(wave)}
+
+    def _step(self, cache, tok):
+        """Dispatch one decode of the wave's last tokens."""
+        tok, cache = self._decode(self.params, cache, {"tokens": tok})
+        obs.add("engine.decode_steps")
+        return tok, cache
 
     def warmup(self, prompt_len: int = 4, wave_size: Optional[int] = None
                ) -> None:

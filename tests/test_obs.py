@@ -172,34 +172,48 @@ def test_profile_holds_every_program_span_on_the_rings_clock(tmp_path):
 
 
 def test_engine_counts_decode_calls_and_token_reads(monkeypatch):
+    """One transfer of the token vector a pass, no per-row ``int()`` read,
+    and every decode of a wave without EOS dispatched before its pass's
+    read."""
     from jax._src.array import ArrayImpl
 
     answers = [5, 3, 1]
     engine = ServeEngine(EchoModel(), batch_size=4, max_seq=32,
                          rng=jax.random.PRNGKey(0))
     engine.generate(reqs(4, [2, 2, 2]))                     # compile
-    calls, reads = [], []
-    orig_decode, orig_int = engine._decode, ArrayImpl.__int__
+    calls, ints, transfers = [], [], []
+    orig_decode, orig_get = engine._decode, jax.device_get
+    orig_int = ArrayImpl.__int__
 
     def decode(*a):
         calls.append(1)
         return orig_decode(*a)
 
     def to_int(self):
-        reads.append(1)
+        ints.append(1)
         return orig_int(self)
+
+    def device_get(x):
+        transfers.append(x.shape)
+        return orig_get(x)
 
     engine._decode = decode
     monkeypatch.setattr(ArrayImpl, "__int__", to_int)
+    monkeypatch.setattr(jax, "device_get", device_get)
     before = obs.snapshot()
     out = engine.generate(reqs(4, answers, uid0=10))
     after = obs.snapshot()
     monkeypatch.undo()
     assert [len(out[10 + i]) for i in range(3)] == answers
-    steps = after["engine.decode_steps"] - before["engine.decode_steps"]
-    pulls = after["engine.host_pulls"] - before["engine.host_pulls"]
-    assert steps == len(calls) == max(answers) - 1
-    assert pulls == len(reads) == 2 * sum(answers)
+
+    def moved(name):
+        return after[name] - before.get(name, 0)
+
+    assert moved("engine.decode_steps") == len(calls) == max(answers) - 1
+    assert moved("engine.host_pulls") == max(answers)
+    assert transfers == [(3, 1)] * max(answers)
+    assert ints == []
+    assert moved("engine.decode_ahead") == max(answers) - 1
     wave, = [r for r in obs.spans() if r.name == "engine.wave"
              and r.attrs["uids"] == [10, 11, 12]]
     inside = [r for r in obs.spans() if r.parent == wave.id]

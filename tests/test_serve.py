@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import SMOKES
 from repro.models.registry import build_model
 from repro.serve.engine import Request, ServeEngine, tune_engine_batch
@@ -171,6 +172,115 @@ def test_engine_warmup_compiles_decode():
     calls = _count_decodes(eng)
     eng.warmup()
     assert len(calls) >= 1
+
+
+# =============================================================================
+# The decode loop against a plain per-row loop: same tokens, same decode calls
+# =============================================================================
+LOOP_MODELS = {
+    "echo": EchoModel,
+    "qwen-smoke": lambda: build_model(SMOKES["qwen1.5-0.5b"]),
+}
+# budgets, and {row: the pass whose token (without EOS) becomes its EOS}
+LOOP_CASES = {
+    "mixed_budgets": ([5, 3, 1, 4], {}),
+    "zero_budget": ([0, 4, 2], {}),
+    "all_zero": ([0, 0], {}),
+    "eos_beside_no_eos": ([6, 6], {0: 2}),
+    "all_eos_same_pass": ([6, 6, 6], {0: 3, 1: 3, 2: 3}),
+}
+
+
+@pytest.fixture(scope="module")
+def loop_engines():
+    """One engine per model, and a jitted plain decode on its weights."""
+    out = {}
+    for name, make in LOOP_MODELS.items():
+        model = make()
+        eng = ServeEngine(model, batch_size=4, max_seq=32,
+                          rng=jax.random.PRNGKey(3))
+        out[name] = (eng, jax.jit(model.decode))
+    return out
+
+
+def _plain_loop(engine, decode, wave):
+    """The decode loop as a plain per-row loop: every live row reads its
+    token from the device on its own, and a decode follows any pass that
+    leaves a row live.  Returns (tokens by row, decode calls)."""
+    plen = max(len(r.prompt) for r in wave)
+    toks = np.zeros((len(wave), plen), np.int32)
+    for i, r in enumerate(wave):
+        toks[i, plen - len(r.prompt):] = r.prompt
+    logits, cache = engine.model.prefill(
+        engine.params, {"tokens": jnp.asarray(toks)}, max_seq=engine.max_seq)
+    gen = [[] for _ in wave]
+    live = [r.max_new_tokens > 0 for r in wave]
+    calls = 0
+    while True:
+        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        for i, r in enumerate(wave):
+            if live[i]:
+                gen[i].append(int(nxt[i]))
+                if gen[i][-1] == r.eos_id or len(gen[i]) >= r.max_new_tokens:
+                    live[i] = False
+        if not any(live):
+            return gen, calls
+        logits, cache = decode(engine.params, cache, {"tokens": nxt[:, None]})
+        calls += 1
+
+
+def _loop_wave(budgets, eos=()):
+    eos = dict(eos)
+    return [Request(uid=i, prompt=(np.arange(3 + i, dtype=np.int32) * 53
+                                   + 29 * i) % 500 + 1,
+                    max_new_tokens=b, eos_id=eos.get(i, -1))
+            for i, b in enumerate(budgets)]
+
+
+@pytest.mark.parametrize("case", sorted(LOOP_CASES))
+@pytest.mark.parametrize("model", sorted(LOOP_MODELS))
+def test_decode_loop_matches_plain_per_row_loop(loop_engines, model, case,
+                                                monkeypatch):
+    eng, decode = loop_engines[model]
+    budgets, eos_at = LOOP_CASES[case]
+    if eos_at:
+        # each EOS row's EOS is its own token at the given pass
+        free, _ = _plain_loop(eng, decode, _loop_wave(budgets))
+        eos = {i: free[i][p] for i, p in eos_at.items()}
+    else:
+        eos = {}
+    want, want_calls = _plain_loop(eng, decode, _loop_wave(budgets, eos))
+    for i, p in eos_at.items():
+        assert len(want[i]) == p + 1, (i, want[i])    # the case is as named
+    calls = []
+    orig = eng._decode
+
+    def counting(*a):
+        calls.append(1)
+        return orig(*a)
+
+    monkeypatch.setattr(eng, "_decode", counting)
+    before = obs.snapshot()
+    out = eng.generate(_loop_wave(budgets, eos))
+    ahead = (obs.snapshot().get("engine.decode_ahead", 0)
+             - before.get("engine.decode_ahead", 0))
+    assert [out[i] for i in range(len(budgets))] == want
+    assert len(calls) == want_calls
+    if len(eos) == len(budgets):
+        assert ahead == 0         # every row may end on EOS: nothing ahead
+    elif not eos:
+        assert ahead == want_calls
+
+
+@pytest.mark.parametrize("model", sorted(LOOP_MODELS))
+def test_engine_decode_lowers_as_jit_decode(loop_engines, model):
+    """The benchmark finds the decode program in a trace by this name."""
+    eng, _ = loop_engines[model]
+    toks = jnp.ones((2, 4), jnp.int32)
+    _, cache = eng.model.prefill(eng.params, {"tokens": toks},
+                                 max_seq=eng.max_seq)
+    lowered = eng._decode.lower(eng.params, cache, {"tokens": toks[:, :1]})
+    assert lowered.as_text().startswith("module @jit_decode")
 
 
 # =============================================================================
