@@ -253,6 +253,11 @@ class ServeCell:
             now = 0.0
             while now < T:
                 tracer.poll(now)
+                # the profiler's stop holds the host for tens of seconds:
+                # the next tick's mix is the one due when it begins
+                now = time.perf_counter() - t0
+                if now >= T:
+                    break
                 mix = self.traffic.mix_at(now)
                 with jax.profiler.TraceAnnotation("make_requests"):
                     reqs = self.requests(mix, int(loop["clients"]), due=now)
