@@ -17,7 +17,8 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import ParamDef, Params, apply_rope, dense
+from repro.models.common import (YaRN, ParamDef, Params, apply_rope, dense,
+                                 rms_norm)
 
 # §Perf switch: causal upper-triangle block skipping in train/prefill
 # attention (see _causal_block_attention).  Module-level so experiments can
@@ -37,6 +38,8 @@ class AttnConfig(NamedTuple):
     kv_lora_rank: int = 0          # 0 => plain GQA
     qk_rope_dim: int = 64
     v_head_dim: int = 0            # defaults to head_dim
+    latent_norm: bool = False      # RMSNorm on the latent before caching
+    yarn: Optional[YaRN] = None    # rotary scaling of the rope part
 
 
 # =============================================================================
@@ -62,6 +65,7 @@ def _causal_block_attention(
     k: jax.Array,   # (B, S, Hkv, D)
     v: jax.Array,   # (B, S, Hkv, Dv)
     chunk: int,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Causal attention with upper-triangle block SKIPPING.
 
@@ -75,7 +79,7 @@ def _causal_block_attention(
     """
     b, s, h, d = q.shape
     if s % chunk != 0 or s // chunk <= 1:
-        return _chunked_attention(q, k, v, True, chunk=chunk)
+        return _chunked_attention(q, k, v, True, chunk=chunk, scale=scale)
     nq = s // chunk
     outs = []
     for i in range(nq):
@@ -83,7 +87,7 @@ def _causal_block_attention(
         kv_len = (i + 1) * chunk
         outs.append(_chunked_attention(
             qi, k[:, :kv_len], v[:, :kv_len], True,
-            q_offset=i * chunk, chunk=chunk))
+            q_offset=i * chunk, chunk=chunk, scale=scale))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -94,13 +98,16 @@ def _chunked_attention(
     causal: bool,
     q_offset: int = 0,
     chunk: int = 1024,
+    scale: Optional[float] = None,
 ) -> jax.Array:
-    """Flash-style online-softmax attention chunked over the KV axis."""
+    """Flash-style online-softmax attention chunked over the KV axis; the
+    scores are scaled by ``scale``, 1/sqrt(D) where None."""
     b, s, h, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
     dv = v.shape[-1]
     rep = h // hkv
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     # operands stay in the model dtype; MXU accumulates f32 via
     # preferred_element_type — no f32 copy of K/V ever hits HBM
     qf = (q * scale).reshape(b, s, hkv, rep, d)
@@ -216,7 +223,7 @@ def mla_defs(cfg: AttnConfig) -> Dict[str, ParamDef]:
     d, h, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
     r, dr = cfg.kv_lora_rank, cfg.qk_rope_dim
     dv = cfg.v_head_dim or hd
-    return {
+    defs = {
         # queries: nope part + rope part per head
         "wq": ParamDef((d, h * (hd + dr)), ("embed", "heads")),
         # KV joint compression to rank r; decompression to K_nope and V
@@ -227,6 +234,9 @@ def mla_defs(cfg: AttnConfig) -> Dict[str, ParamDef]:
         "w_kr": ParamDef((d, dr), ("embed", None)),
         "wo": ParamDef((h * dv, d), ("heads", "embed")),
     }
+    if cfg.latent_norm:
+        defs["kv_ln"] = ParamDef((r,), (None,), init="ones")
+    return defs
 
 
 def mla_apply(
@@ -245,12 +255,20 @@ def mla_apply(
     q = dense(x, p["wq"]).reshape(b, s, h, hd + dr)
     q_nope, q_rope = q[..., :hd], q[..., hd:]
     c_kv = dense(x, p["w_dkv"])                     # (B, S, R) — the cache
+    if cfg.latent_norm:
+        c_kv = rms_norm(c_kv, p["kv_ln"])
     k_rope = dense(x, p["w_kr"])                    # (B, S, Dr) shared
+    # scores are divided by ``denom``: sqrt(hd + dr), over YaRN's factor
+    denom = math.sqrt(hd + dr) / (cfg.yarn.softmax_factor
+                                  if cfg.yarn is not None else 1.0)
+
+    def rope(t, pos):
+        return apply_rope(t, pos, cfg.rope_theta, cfg.yarn)
 
     if cache is None:
         pos = positions if positions is not None else jnp.arange(s)[None, :]
-        q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
-        k_rope_r = apply_rope(k_rope[:, :, None, :], pos, cfg.rope_theta)
+        q_rope = rope(q_rope, pos)
+        k_rope_r = rope(k_rope[:, :, None, :], pos)
         k_nope = dense(c_kv, p["w_uk"]).reshape(b, s, h, hd)
         v = dense(c_kv, p["w_uv"]).reshape(b, s, h, dv)
         # concatenated effective head dims: [nope | rope]
@@ -259,16 +277,17 @@ def mla_apply(
             [k_nope, jnp.broadcast_to(k_rope_r, (b, s, h, dr))], axis=-1
         )
         if cfg.causal and CAUSAL_BLOCK_SKIP:
-            out = _causal_block_attention(q_full, k_full, v, chunk=kv_chunk)
+            out = _causal_block_attention(q_full, k_full, v, chunk=kv_chunk,
+                                          scale=1.0 / denom)
         else:
             out = _chunked_attention(q_full, k_full, v, cfg.causal,
-                                     chunk=kv_chunk)
-        new_cache = None
+                                     chunk=kv_chunk, scale=1.0 / denom)
+        # what a decode reads: the normed latent and the rotated key
+        new_cache = {"c_kv": c_kv, "k_rope": k_rope_r[:, :, 0, :]}
     else:
         pos = cache["pos"]
-        q_rope = apply_rope(q_rope, pos[None, None], cfg.rope_theta)
-        k_rope_r = apply_rope(k_rope[:, :, None, :], pos[None, None],
-                              cfg.rope_theta)[:, :, 0, :]
+        q_rope = rope(q_rope, pos[None, None])
+        k_rope_r = rope(k_rope[:, :, None, :], pos[None, None])[:, :, 0, :]
         ckv_c, kr_c = cache["c_kv"], cache["k_rope"]
         t = ckv_c.shape[1]
         # absorbed attention: score = q_nope^T W_uk c_kv + q_rope^T k_rope
@@ -279,7 +298,7 @@ def mla_apply(
                             ckv_c, preferred_element_type=jnp.float32)
         s_rope = jnp.einsum("bshd,btd->bhst", q_rope, kr_c,
                             preferred_element_type=jnp.float32)
-        sij = (s_nope + s_rope) / math.sqrt(hd + dr)
+        sij = (s_nope + s_rope) / denom
         valid = jnp.arange(t)[None, :] < pos
         sij = jnp.where(valid[None, None], sij, -1e30)
         # current token's own score (cache not yet updated)
@@ -287,7 +306,7 @@ def mla_apply(
                              c_kv, preferred_element_type=jnp.float32)
                   + jnp.einsum("bshd,bsd->bhs", q_rope, k_rope_r,
                                preferred_element_type=jnp.float32)
-                  ) / math.sqrt(hd + dr)
+                  ) / denom
         sij = jnp.concatenate([sij, s_self[..., None]], axis=-1)
         pr = jax.nn.softmax(sij, axis=-1)
         ctx = jnp.einsum("bhst,btr->bshr",

@@ -24,6 +24,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = Dict[str, Any]
 
@@ -133,14 +134,70 @@ def rope_freqs(head_dim: int, max_pos: int, theta: float = 10000.0):
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling, as DeepSeek-V2's ``rope_scaling`` of type
+    ``yarn`` states it: frequencies whose wavelength fits
+    ``original_max_position`` more than ``beta_fast`` times keep their
+    value, those fitting fewer than ``beta_slow`` times are divided by
+    ``factor``, and the ones between are ramped linearly; the softmax scale
+    is multiplied by ``softmax_factor``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def inv_freq(self, dim: int, theta: float) -> np.ndarray:
+        """(dim/2,) float32 inverse frequencies of a rotary dimension."""
+        def correction(rotations):
+            return (dim * math.log(self.original_max_position
+                                   / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(correction(self.beta_fast)), 0)
+        high = min(math.ceil(correction(self.beta_slow)), dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                       / (high - low), 0.0, 1.0)
+        extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+        return (extra / self.factor * ramp + extra * (1.0 - ramp)
+                ).astype(np.float32)
+
+    @property
+    def rotary_scale(self) -> float:
+        """Factor on cos and sin (1 where ``mscale`` equals
+        ``mscale_all_dim``)."""
+        return (_yarn_mscale(self.factor, self.mscale)
+                / _yarn_mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_factor(self) -> float:
+        return _yarn_mscale(self.factor, self.mscale_all_dim) ** 2
+
+
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float = 10000.0) -> jax.Array:
-    """x: (..., S, H, D); positions: (..., S) int32."""
+               theta: float = 10000.0, yarn: Optional[YaRN] = None
+               ) -> jax.Array:
+    """x: (..., S, H, D); positions: (..., S) int32.  Rotates interleaved
+    pairs (x[2i], x[2i+1])."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    else:
+        inv = jnp.asarray(yarn.inv_freq(d, theta))
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., S, D/2)
     cos = jnp.cos(ang)[..., None, :]   # broadcast over heads
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None and yarn.rotary_scale != 1.0:
+        cos, sin = cos * yarn.rotary_scale, sin * yarn.rotary_scale
     x1, x2 = x[..., ::2], x[..., 1::2]
     y1 = x1 * cos - x2 * sin
     y2 = x1 * sin + x2 * cos

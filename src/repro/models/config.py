@@ -4,6 +4,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+from repro.models.common import YaRN
+
 
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
@@ -25,10 +27,15 @@ class ArchConfig:
     top_k: int = 0
     n_shared_experts: int = 0
     moe_d_ff: int = 0            # per-expert hidden (0 => d_ff)
+    first_k_dense: int = 0       # leading dense layers (width d_ff) of an MoE
+    moe_norm_topk: bool = True   # renormalise the top-k gates to sum to one
+    expert_share: Tuple[int, ...] = ()  # (first, count) of experts held
     # MLA (DeepSeek-V2)
     kv_lora_rank: int = 0
     qk_rope_dim: int = 64
     v_head_dim: int = 0
+    mla_latent_norm: bool = False  # RMSNorm on the latent (kv_a_layernorm)
+    rope_yarn: Optional[YaRN] = None  # YaRN rotary scaling of the rope part
     # hybrid / SSM
     ssm_state: int = 0
     mamba_per_attn: int = 0      # zamba2: mamba layers per shared-attn block

@@ -57,7 +57,9 @@ class Model:
             return encdec.encdec_prefill(cfg, params, batch, max_seq, **opts)
         return transformer.prefill(cfg, params, batch, max_seq, **opts)
 
-    def decode(self, params, cache, batch):
+    def decode(self, params, cache, batch, **opts):
+        """(logits, new cache); an MoE transformer takes ``moe_counts``
+        (``transformer.decode_step``)."""
         cfg = self.cfg
         if cfg.xlstm:
             return hybrid.xlstm_decode(cfg, params, cache, batch)
@@ -65,7 +67,15 @@ class Model:
             return hybrid.zamba2_decode(cfg, params, cache, batch)
         if cfg.enc_layers:
             return encdec.encdec_decode(cfg, params, cache, batch)
-        return transformer.decode_step(cfg, params, cache, batch)
+        return transformer.decode_step(cfg, params, cache, batch, **opts)
+
+    @property
+    def counts_experts(self) -> bool:
+        """Whether ``decode`` takes ``moe_counts``: a transformer with
+        experts."""
+        cfg = self.cfg
+        return bool(cfg.n_experts) and not (cfg.xlstm or cfg.mamba_per_attn
+                                            or cfg.enc_layers)
 
     def init_cache(self, batch: int, max_seq: int):
         cfg = self.cfg

@@ -3,7 +3,10 @@ optional vision/audio embedding prefix (VLM stub per assignment).
 
 Layers are scanned (stacked params, ``jax.lax.scan``) to keep HLO size
 O(1) in depth — essential for 512-device SPMD compiles.  Remat is applied
-per-layer via ``jax.checkpoint`` with a configurable policy.
+per-layer via ``jax.checkpoint`` with a configurable policy.  An MoE model
+with ``first_k_dense`` leading dense layers has two stacks, scanned in turn:
+``dense_blocks`` and then ``blocks``; its serving cache keeps the leading
+stack's layers under the key ``dense_blocks``.
 """
 from __future__ import annotations
 
@@ -17,8 +20,9 @@ from repro.distributed.api import shard_act
 from repro.models.attention import (AttnConfig, gqa_apply, gqa_defs,
                                     gqa_init_cache, mla_apply, mla_defs,
                                     mla_init_cache)
-from repro.models.common import (ParamDef, Params, cross_entropy_from_hidden,
-                                 dense, init_params, logical_specs, mlp_apply,
+from repro.models.common import (ParamDef, Params, apply_rope,
+                                 cross_entropy_from_hidden, dense,
+                                 init_params, logical_specs, mlp_apply,
                                  mlp_defs, rms_norm, stack_defs)
 from repro.models.config import ArchConfig
 from repro.models.moe import (MoEConfig, moe_apply,
@@ -36,6 +40,8 @@ def attn_config(cfg: ArchConfig) -> AttnConfig:
         kv_lora_rank=cfg.kv_lora_rank,
         qk_rope_dim=cfg.qk_rope_dim,
         v_head_dim=cfg.v_head_dim,
+        latent_norm=cfg.mla_latent_norm,
+        yarn=cfg.rope_yarn,
     )
 
 
@@ -46,20 +52,34 @@ def moe_config(cfg: ArchConfig) -> MoEConfig:
         n_experts=cfg.n_experts,
         top_k=cfg.top_k,
         n_shared=cfg.n_shared_experts,
+        norm_topk=cfg.moe_norm_topk,
+        share=cfg.expert_share,
     )
+
+
+LEAD = "dense_blocks"
+
+
+def stacks(cfg: ArchConfig) -> Tuple[Tuple[str, int, bool], ...]:
+    """(params key, layers, MoE?) of each scanned stack, in order."""
+    moe = bool(cfg.n_experts)
+    k = cfg.first_k_dense
+    if not k:
+        return (("blocks", cfg.n_layers, moe),)
+    return ((LEAD, k, False), ("blocks", cfg.n_layers - k, moe))
 
 
 # =============================================================================
 # Parameter definitions
 # =============================================================================
-def block_defs(cfg: ArchConfig) -> Dict[str, Any]:
+def block_defs(cfg: ArchConfig, moe: bool) -> Dict[str, Any]:
     acfg = attn_config(cfg)
     defs: Dict[str, Any] = {
         "ln1": ParamDef((cfg.d_model,), ("embed",), init="ones"),
         "ln2": ParamDef((cfg.d_model,), ("embed",), init="ones"),
         "attn": mla_defs(acfg) if cfg.kv_lora_rank else gqa_defs(acfg),
     }
-    if cfg.n_experts:
+    if moe:
         defs["moe"] = moe_defs(moe_config(cfg))
     else:
         defs["mlp"] = mlp_defs(cfg.d_model, cfg.d_ff, gated=True)
@@ -70,9 +90,10 @@ def lm_defs(cfg: ArchConfig) -> Dict[str, Any]:
     v = cfg.padded_vocab
     defs: Dict[str, Any] = {
         "embed": ParamDef((v, cfg.d_model), ("vocab", "embed"), scale=0.02),
-        "blocks": stack_defs(block_defs(cfg), cfg.n_layers),
         "final_ln": ParamDef((cfg.d_model,), ("embed",), init="ones"),
     }
+    for key, n, moe in stacks(cfg):
+        defs[key] = stack_defs(block_defs(cfg, moe), n)
     if not cfg.tie_embeddings:
         defs["lm_head"] = ParamDef((cfg.d_model, v), ("embed", "vocab"),
                                    scale=0.02)
@@ -85,18 +106,21 @@ def lm_defs(cfg: ArchConfig) -> Dict[str, Any]:
 # =============================================================================
 # Forward
 # =============================================================================
-def _block_apply(cfg: ArchConfig, lp: Params, x: jax.Array,
-                 kv_chunk: int) -> Tuple[jax.Array, jax.Array]:
+def _attn(cfg: ArchConfig, p: Params, h: jax.Array, **kw):
     acfg = attn_config(cfg)
-    h = rms_norm(x, lp["ln1"])
     if cfg.kv_lora_rank:
-        h, _ = mla_apply(lp["attn"], acfg, h, kv_chunk=kv_chunk)
-    else:
-        h, _ = gqa_apply(lp["attn"], acfg, h, kv_chunk=kv_chunk)
+        with jax.named_scope("mla"):
+            return mla_apply(p, acfg, h, **kw)
+    return gqa_apply(p, acfg, h, **kw)
+
+
+def _block_apply(cfg: ArchConfig, lp: Params, x: jax.Array,
+                 kv_chunk: int, moe: bool) -> Tuple[jax.Array, jax.Array]:
+    h, _ = _attn(cfg, lp["attn"], rms_norm(x, lp["ln1"]), kv_chunk=kv_chunk)
     x = x + h
     x = shard_act(x, ("batch", None, None))
     h = rms_norm(x, lp["ln2"])
-    if cfg.n_experts:
+    if moe:
         h, aux = moe_apply(lp["moe"], moe_config(cfg), h)
     else:
         h, aux = mlp_apply(lp["mlp"], h, cfg.activation), jnp.float32(0.0)
@@ -118,24 +142,24 @@ def forward_hidden(
 ) -> Tuple[jax.Array, jax.Array]:
     """Token (+prefix) embeddings -> final hidden states, scanning layers."""
     x = embed_inputs(cfg, params, batch)
+    aux = jnp.float32(0.0)
+    for key, _, moe in stacks(cfg):
+        def body(carry, lp, moe=moe):
+            x, aux = carry
+            x, a = _block_apply(cfg, lp, x, kv_chunk, moe)
+            return (x, aux + a), None
 
-    def body(carry, lp):
-        x, aux = carry
-        x, a = _block_apply(cfg, lp, x, kv_chunk)
-        return (x, aux + a), None
+        body_fn = body
+        if remat != "none":
+            policy = {
+                "nothing_saveable": jax.checkpoint_policies.nothing_saveable,
+                "dots_saveable": jax.checkpoint_policies.dots_saveable,
+                "dots_with_no_batch_dims": (
+                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable),
+            }[remat]
+            body_fn = jax.checkpoint(body, policy=policy)
 
-    body_fn = body
-    if remat != "none":
-        policy = {
-            "nothing_saveable": jax.checkpoint_policies.nothing_saveable,
-            "dots_saveable": jax.checkpoint_policies.dots_saveable,
-            "dots_with_no_batch_dims": (
-                jax.checkpoint_policies.dots_with_no_batch_dims_saveable),
-        }[remat]
-        body_fn = jax.checkpoint(body, policy=policy)
-
-    (x, aux), _ = jax.lax.scan(body_fn, (x, jnp.float32(0.0)),
-                               params["blocks"])
+        (x, aux), _ = jax.lax.scan(body_fn, (x, aux), params[key])
     return rms_norm(x, params["final_ln"]), aux
 
 
@@ -163,47 +187,74 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype) -> Dict:
     acfg = attn_config(cfg)
     one = (mla_init_cache(acfg, batch, max_seq, dtype) if cfg.kv_lora_rank
            else gqa_init_cache(acfg, batch, max_seq, dtype))
+
     # stack along layers for scan: every leaf gets a leading L axis
-    return jax.tree.map(
-        lambda a: jnp.broadcast_to(a, (cfg.n_layers,) + a.shape).copy(), one
-    )
+    def stacked(n):
+        return jax.tree.map(
+            lambda a: jnp.broadcast_to(a, (n,) + a.shape).copy(), one)
+
+    return _join_cache({key: stacked(n) for key, n, _ in stacks(cfg)})
+
+
+def _split_cache(cache: Dict) -> Dict[str, Dict]:
+    """The serving cache as one stacked cache per stack of ``stacks``."""
+    out = {"blocks": {k: v for k, v in cache.items() if k != LEAD}}
+    if LEAD in cache:
+        out[LEAD] = cache[LEAD]
+    return out
+
+
+def _join_cache(per_stack: Dict[str, Dict]) -> Dict:
+    cache = dict(per_stack["blocks"])
+    if LEAD in per_stack:
+        cache[LEAD] = per_stack[LEAD]
+    return cache
 
 
 def decode_step(
     cfg: ArchConfig, params: Params, cache: Dict, batch: Dict,
-) -> Tuple[jax.Array, Dict]:
+    moe_counts: bool = False,
+) -> Tuple[jax.Array, ...]:
     """One-token decode: batch["tokens"]: (B, 1) -> (logits, new cache).
 
     Layers are scanned; each layer emits only the NEW token's K/V.  The
     stacked cache is updated ONCE after the scan (a single in-place
     token-slot write instead of per-layer full-buffer rewrites).
+
+    With ``moe_counts`` it returns (logits, new cache, counts) as well:
+    int32 (routed pairs of held experts, held experts that received a
+    pair), summed over the MoE layers.
     """
-    acfg = attn_config(cfg)
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
-
-    def body(x, scanned):
-        lp, cache_l = scanned
-        h = rms_norm(x, lp["ln1"])
-        if cfg.kv_lora_rank:
-            h, new_c = mla_apply(lp["attn"], acfg, h, cache=cache_l)
-        else:
-            h, new_c = gqa_apply(lp["attn"], acfg, h, cache=cache_l)
-        x = x + h
-        h = rms_norm(x, lp["ln2"])
-        if cfg.n_experts:
-            h = moe_apply_dropless(lp["moe"], moe_config(cfg), h)
-        else:
+    caches = _split_cache(cache)
+    counts = jnp.zeros(2, jnp.int32)
+    for key, _, moe in stacks(cfg):
+        def body(x, scanned, moe=moe):
+            lp, cache_l = scanned
+            h, new_c = _attn(cfg, lp["attn"], rms_norm(x, lp["ln1"]),
+                             cache=cache_l)
+            x = x + h
+            h = rms_norm(x, lp["ln2"])
+            if moe:
+                with jax.named_scope("moe"):
+                    h, c = moe_apply_dropless(lp["moe"], moe_config(cfg), h)
+                return x + h, (new_c, c)
             h = mlp_apply(lp["mlp"], h, cfg.activation)
-        return x + h, new_c
+            return x + h, new_c
 
-    x, new_kv = jax.lax.scan(body, x, (params["blocks"], cache))
-    new_cache = update_stacked_cache(cfg, cache, new_kv)
+        x, out = jax.lax.scan(body, x, (params[key], caches[key]))
+        if moe:
+            out, c = out
+            counts = counts + jnp.sum(c, axis=0)
+        caches[key] = update_stacked_cache(cfg, caches[key], out)
     x = rms_norm(x, params["final_ln"])
     w_out = params.get("lm_head")
     if w_out is None:
         w_out = params["embed"].T
     logits = dense(x, w_out)
-    return logits, new_cache
+    if moe_counts:
+        return logits, _join_cache(caches), counts
+    return logits, _join_cache(caches)
 
 
 def update_stacked_cache(cfg: ArchConfig, cache: Dict, new_kv: Dict) -> Dict:
@@ -229,61 +280,61 @@ def prefill(
     """Process the prompt, building the KV cache; returns last-pos logits.
 
     Implemented as forward_hidden for the hidden states plus cache
-    construction per layer (recomputing K/V projections — cheap relative to
-    attention itself and keeps the scan carry small).
+    construction per layer.  MLA caches what its own prefill returns (the
+    normed latent and the rotated key); GQA recomputes its K/V projections
+    (cheap relative to attention itself and keeps the scan carry small).
     """
     acfg = attn_config(cfg)
     x = embed_inputs(cfg, params, batch)
     b, s, _ = x.shape
     pad = max_seq - s
-
-    def body(x, lp):
-        h = rms_norm(x, lp["ln1"])
-        if cfg.kv_lora_rank:
-            c_kv = dense(h, lp["attn"]["w_dkv"])
-            from repro.models.common import apply_rope
-            k_rope = apply_rope(
-                dense(h, lp["attn"]["w_kr"])[:, :, None, :],
-                jnp.arange(s)[None, :], cfg.rope_theta)[:, :, 0, :]
-            cache_l = {
-                "c_kv": jnp.pad(c_kv, ((0, 0), (0, pad), (0, 0))),
-                "k_rope": jnp.pad(k_rope, ((0, 0), (0, pad), (0, 0))),
-                "pos": jnp.int32(s),
-            }
-            h, _ = mla_apply(lp["attn"], acfg, h)
-        else:
-            hk, hd = acfg.n_kv_heads, acfg.head_dim
-            from repro.models.common import apply_rope
-            k = dense(h, lp["attn"]["wk"], lp["attn"].get("bk")).reshape(
-                b, s, hk, hd)
-            k = apply_rope(k, jnp.arange(s)[None, :], cfg.rope_theta)
-            v = dense(h, lp["attn"]["wv"], lp["attn"].get("bv")).reshape(
-                b, s, hk, hd)
-            cache_l = {
-                "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                "pos": jnp.int32(s),
-            }
-            h, _ = gqa_apply(lp["attn"], acfg, h)
-        x = x + h
-        h = rms_norm(x, lp["ln2"])
-        if cfg.n_experts:
-            # §Perf: global argsort dispatch all-gathers the full token set
-            # across the data axis — at large-T prefill the grouped-capacity
-            # einsum dispatch keeps routing local to each shard (the
-            # collective-bound fix for llama4-scout prefill_32k); dropless
-            # stays for small T where exactness is cheap
-            if b * s > 65536:
-                h, _ = moe_apply(lp["moe"], moe_config(cfg), h)
+    caches = {}
+    for key, _, moe in stacks(cfg):
+        def body(x, lp, moe=moe):
+            h = rms_norm(x, lp["ln1"])
+            if cfg.kv_lora_rank:
+                h, c = _attn(cfg, lp["attn"], h)
+                cache_l = {
+                    "c_kv": jnp.pad(c["c_kv"], ((0, 0), (0, pad), (0, 0))),
+                    "k_rope": jnp.pad(c["k_rope"],
+                                      ((0, 0), (0, pad), (0, 0))),
+                    "pos": jnp.int32(s),
+                }
             else:
-                h = moe_apply_dropless(lp["moe"], moe_config(cfg), h)
-        else:
-            h = mlp_apply(lp["mlp"], h, cfg.activation)
-        return x + h, cache_l
+                hk, hd = acfg.n_kv_heads, acfg.head_dim
+                k = dense(h, lp["attn"]["wk"], lp["attn"].get("bk")).reshape(
+                    b, s, hk, hd)
+                k = apply_rope(k, jnp.arange(s)[None, :], cfg.rope_theta)
+                v = dense(h, lp["attn"]["wv"], lp["attn"].get("bv")).reshape(
+                    b, s, hk, hd)
+                cache_l = {
+                    "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
+                    "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
+                    "pos": jnp.int32(s),
+                }
+                h, _ = gqa_apply(lp["attn"], acfg, h)
+            x = x + h
+            h = rms_norm(x, lp["ln2"])
+            if moe:
+                # §Perf: global argsort dispatch all-gathers the full token
+                # set across the data axis — at large-T prefill the
+                # grouped-capacity einsum dispatch keeps routing local to
+                # each shard (the collective-bound fix for llama4-scout
+                # prefill_32k); dropless stays for small T where exactness
+                # is cheap
+                with jax.named_scope("moe"):
+                    if b * s > 65536:
+                        h, _ = moe_apply(lp["moe"], moe_config(cfg), h)
+                    else:
+                        h, _ = moe_apply_dropless(lp["moe"], moe_config(cfg),
+                                                  h)
+            else:
+                h = mlp_apply(lp["mlp"], h, cfg.activation)
+            return x + h, cache_l
 
-    x, cache = jax.lax.scan(body, x, params["blocks"])
+        x, caches[key] = jax.lax.scan(body, x, params[key])
     x = rms_norm(x[:, -1:], params["final_ln"])
     w_out = params.get("lm_head")
     if w_out is None:
         w_out = params["embed"].T
-    return dense(x, w_out), cache
+    return dense(x, w_out), _join_cache(caches)

@@ -122,11 +122,25 @@ class ServeWorkloadStats:
     d_model: int = 4096
     n_layers: int = 24
     bytes_per_value: int = 2     # bf16
+    cache_bytes_per_pos: float = 0.0   # 0: a full multi-head K+V cache
 
     @property
     def kv_bytes_per_pos(self) -> float:
-        """K+V cache bytes per sequence position, all layers."""
+        """Cache bytes per sequence position, all layers."""
+        if self.cache_bytes_per_pos:
+            return self.cache_bytes_per_pos
         return 2.0 * self.n_layers * self.d_model * self.bytes_per_value
+
+
+def cache_bytes_per_position(model) -> int:
+    """Bytes the model's serving cache adds for one more position of one
+    row, read from its cache's shapes (nothing is allocated)."""
+    import jax
+
+    def size(max_seq):
+        return sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(model.cache_specs(1, max_seq)))
+    return size(2) - size(1)
 
 
 def stats_from_model(model, bytes_per_value: int = 2) -> ServeWorkloadStats:
@@ -136,7 +150,8 @@ def stats_from_model(model, bytes_per_value: int = 2) -> ServeWorkloadStats:
         param_bytes=float(model.param_count()) * bytes_per_value,
         d_model=int(cfg.d_model),
         n_layers=int(cfg.n_layers),
-        bytes_per_value=bytes_per_value)
+        bytes_per_value=bytes_per_value,
+        cache_bytes_per_pos=float(cache_bytes_per_position(model)))
 
 
 def serve_workload_fn(n_requests: int, prompt_len: int, new_tokens: int,

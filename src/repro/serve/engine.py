@@ -17,7 +17,11 @@ whatever those tokens say, it is dispatched before the read, so the device
 decodes while the host waits for and handles the pass.  Counters
 (``repro.obs``): ``engine.decode_steps``, one a decode dispatched;
 ``engine.host_pulls``, one a pass (its transfer); ``engine.decode_ahead``,
-one a decode dispatched before its pass's read.
+one a decode dispatched before its pass's read.  The decode of a model with
+experts also counts, summed over its MoE layers, the routed (token, expert)
+pairs of the experts held here and the held experts that received one; the
+two numbers ride in the pass's one transfer behind the tokens and move
+``moe.pairs_held`` and ``moe.experts_touched``.
 """
 from __future__ import annotations
 
@@ -52,10 +56,17 @@ class ServeEngine:
 
         # the greedy pick runs inside the program (still ``jit_decode``), so a
         # pass hands the host one (n, 1) vector that also feeds the next call
+        self._counted = getattr(model, "counts_experts", False)
+
         def decode(params, cache, batch):
-            logits, cache = model.decode(params, cache, batch)
-            return (jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32),
-                    cache)
+            if not self._counted:
+                logits, cache = model.decode(params, cache, batch)
+                return (jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32),
+                        cache)
+            logits, cache, counts = model.decode(params, cache, batch,
+                                                 moe_counts=True)
+            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+            return (tok, jnp.concatenate([tok[:, 0], counts])), cache
 
         self._decode = jax.jit(decode, donate_argnums=(1,))
 
@@ -84,6 +95,7 @@ class ServeEngine:
                 logits, cache = self.model.prefill(self.params, batch,
                                                    max_seq=self.max_seq)
                 tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+                pull = tok
             steps = max(r.max_new_tokens for r in wave)
             done = np.zeros(n, bool)
             gen: List[List[int]] = [[] for _ in range(n)]
@@ -108,7 +120,11 @@ class ServeEngine:
                     obs.add("engine.decode_ahead", int(ahead))
                     obs.add("engine.host_pulls")
                     with obs.span("engine.pull"):
-                        picked = jax.device_get(tok)[:, 0].tolist()
+                        got = np.ravel(jax.device_get(pull))
+                    picked = got[:n].tolist()
+                    if got.size > n:
+                        obs.add("moe.pairs_held", int(got[n]))
+                        obs.add("moe.experts_touched", int(got[n + 1]))
                     for i, r in enumerate(wave):
                         if not done[i]:
                             gen[i].append(picked[i])
@@ -116,18 +132,20 @@ class ServeEngine:
                                     or len(gen[i]) >= r.max_new_tokens):
                                 done[i] = True
                     if ahead:
-                        tok, cache = nxt
+                        tok, pull, cache = nxt
                     elif done.all():
                         break
                     else:
-                        tok, cache = self._step(cache, tok)
+                        tok, pull, cache = self._step(cache, tok)
         return {r.uid: gen[i] for i, r in enumerate(wave)}
 
     def _step(self, cache, tok):
-        """Dispatch one decode of the wave's last tokens."""
-        tok, cache = self._decode(self.params, cache, {"tokens": tok})
+        """Dispatch one decode of the wave's last tokens: (the next tokens,
+        what the pass reads of them, the cache)."""
+        out, cache = self._decode(self.params, cache, {"tokens": tok})
         obs.add("engine.decode_steps")
-        return tok, cache
+        tok, pull = out if self._counted else (out, out)
+        return tok, pull, cache
 
     def warmup(self, prompt_len: int = 4, wave_size: Optional[int] = None
                ) -> None:
