@@ -59,8 +59,8 @@ def moe_defs(cfg: MoEConfig) -> Dict[str, ParamDef]:
     return defs
 
 
-def _route(p: Params, cfg: MoEConfig, x: jax.Array
-           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+def route(p: Params, cfg: MoEConfig, x: jax.Array
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(softmax probabilities, top-k gates, top-k experts) of ``x``'s
     tokens, in float32."""
     logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
@@ -84,7 +84,7 @@ def moe_apply(p: Params, cfg: MoEConfig, x: jax.Array
     g = t // tg
     xg = x.reshape(g, tg, d)
 
-    probs, gate_vals, gate_idx = _route(p, cfg, xg)            # (G, Tg, k)
+    probs, gate_vals, gate_idx = route(p, cfg, xg)             # (G, Tg, k)
 
     # load-balancing auxiliary loss (Switch-style), over all tokens
     me = jnp.mean(probs, axis=(0, 1))                          # (E,)
@@ -127,33 +127,43 @@ def moe_apply(p: Params, cfg: MoEConfig, x: jax.Array
     return out, aux
 
 
-def moe_apply_dropless(p: Params, cfg: MoEConfig, x: jax.Array
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """Inference dispatch: exact, dropless, sorted-by-expert ragged matmuls.
+# Calls of at most this many tokens (T = B * S) take ``held_dense``, every
+# token against every held expert; larger ones ``held_ragged``.  A dense
+# product of T rows does 2 T FLOP per 2-byte bf16 weight, T FLOP a byte; a
+# v5e's ridge is 197e12 / 819e9 = 240 FLOP a byte, so below it the dense
+# product takes no longer than reading each held expert once.  The ragged
+# path reads each held expert in full too, and in a layer scan first copies
+# the layer's slice of the stacked weights: ``ragged_dot`` is a custom call
+# and cannot read a slice in place, where a dot can.  128 keeps a factor of
+# about two to the ridge: decode (T = batch) reads in place, prefills of
+# more than 128 tokens keep the grouped product.
+DENSE_MAX_TOKENS = 128
 
-    Serving paths must be prefill/decode consistent; capacity-bucket drops
-    (acceptable statistical noise in training) would break that, so serving
-    uses argsort dispatch + ``jax.lax.ragged_dot`` — the TPU-native grouped
-    GEMM (vLLM/MegaBlocks-style dropless MoE).  The (token, expert) pairs of
-    experts held elsewhere sort after every held expert's group, outside
-    the groups, and add nothing.
 
-    Returns (out, counts): counts is int32 (pairs routed to held experts,
-    held experts that received at least one pair).
-    """
-    b, sq, d = x.shape
-    t = b * sq
-    k = cfg.top_k
+def _route_local(cfg: MoEConfig, gate_vals: jax.Array, gate_idx: jax.Array
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """(held expert index, gate) of each (token, choice), flattened; pairs
+    routed to experts held elsewhere get index ``n`` and gate 0."""
     first, n = held(cfg)
-    xt = x.reshape(t, d)
-
-    _, gate_vals, gate_idx = _route(p, cfg, xt)              # (T, k)
-
-    local = gate_idx.reshape(-1) - first                      # (T*k,)
+    local = gate_idx.reshape(-1) - first
     mine = (local >= 0) & (local < n)
-    local = jnp.where(mine, local, n)
+    return (jnp.where(mine, local, n),
+            jnp.where(mine, gate_vals.reshape(-1), 0.0))
+
+
+def held_ragged(p: Params, cfg: MoEConfig, xt: jax.Array,
+                gate_vals: jax.Array, gate_idx: jax.Array
+                ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of ``xt`` (T, D) by grouped products over the
+    routed pairs, sorted by expert: (out (T, D), counts).  The pairs of
+    experts held elsewhere sort after every held expert's group, outside
+    the groups, and add nothing.  counts is int32 (pairs routed to held
+    experts, held experts that received at least one pair)."""
+    t, d = xt.shape
+    n = held(cfg)[1]
+    local, gates = _route_local(cfg, gate_vals, gate_idx)
     order = jnp.argsort(local)
-    tok_of = order // k                                       # source token
+    tok_of = order // cfg.top_k                               # source token
     xs = jnp.take(xt, tok_of, axis=0)                         # (T*k, D)
     group_sizes = jnp.bincount(local, length=n + 1)[:n]
 
@@ -162,12 +172,59 @@ def moe_apply_dropless(p: Params, cfg: MoEConfig, x: jax.Array
     ) * jax.lax.ragged_dot(xs, p["w_up"], group_sizes)
     ys = jax.lax.ragged_dot(h, p["w_down"], group_sizes)      # (T*k, D)
 
-    g = jnp.take(jnp.where(mine, gate_vals.reshape(-1), 0.0), order)
+    g = jnp.take(gates, order)
     out = jnp.zeros((t, d), ys.dtype).at[tok_of].add(
         ys * g[:, None].astype(ys.dtype))
+    counts = jnp.stack([jnp.sum(group_sizes), jnp.sum(group_sizes > 0)])
+    return out, counts.astype(jnp.int32)
+
+
+def held_dense(p: Params, cfg: MoEConfig, xt: jax.Array,
+               gate_vals: jax.Array, gate_idx: jax.Array
+               ) -> Tuple[jax.Array, jax.Array]:
+    """As ``held_ragged``, by plain dots of every token with every held
+    expert, each (token, expert) scaled by the token's gate for that expert
+    before the down product: exactly 0 where the token did not route to it.
+    The dots read the weights where they lie, a layer's slice of stacked
+    weights included."""
+    t = xt.shape[0]
+    n = held(cfg)[1]
+    local, gates = _route_local(cfg, gate_vals, gate_idx)
+    routed = local.reshape(t, cfg.top_k, 1) == jnp.arange(n)  # (T, k, n)
+    g = jnp.sum(jnp.where(routed, gates.reshape(t, cfg.top_k, 1), 0.0),
+                axis=1)                                       # (T, n)
+
+    h = jax.nn.silu(
+        jnp.einsum("td,edf->tef", xt, p["w_gate"])
+    ) * jnp.einsum("td,edf->tef", xt, p["w_up"])              # (T, n, F)
+    h = (h.astype(jnp.float32) * g[..., None]).astype(h.dtype)
+    out = jnp.einsum("tef,efd->td", h, p["w_down"])
+    counts = jnp.stack([jnp.sum(routed), jnp.sum(jnp.any(routed, (0, 1)))])
+    return out, counts.astype(jnp.int32)
+
+
+def moe_apply_dropless(p: Params, cfg: MoEConfig, x: jax.Array
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """Inference dispatch: exact and dropless.
+
+    Serving paths must be prefill/decode consistent; capacity-bucket drops
+    (acceptable statistical noise in training) would break that.  Calls of
+    up to ``DENSE_MAX_TOKENS`` tokens take ``held_dense``, larger ones
+    ``held_ragged``: argsort dispatch + ``jax.lax.ragged_dot``, the
+    TPU-native grouped GEMM (vLLM/MegaBlocks-style dropless MoE).
+
+    Returns (out, counts): counts is int32 (pairs routed to held experts,
+    held experts that received at least one pair, 1 where the held experts
+    were read in place by ``held_dense`` else 0).
+    """
+    b, sq, d = x.shape
+    t = b * sq
+    xt = x.reshape(t, d)
+    _, gate_vals, gate_idx = route(p, cfg, xt)               # (T, k)
+    inplace = t <= DENSE_MAX_TOKENS
+    out, counts = (held_dense if inplace else held_ragged)(
+        p, cfg, xt, gate_vals, gate_idx)
     out = out.reshape(b, sq, d).astype(x.dtype)
     if cfg.n_shared:
         out = out + mlp_apply(p["shared"], x)
-    counts = jnp.stack([jnp.sum(group_sizes),
-                        jnp.sum(group_sizes > 0)]).astype(jnp.int32)
-    return out, counts
+    return out, jnp.concatenate([counts, jnp.array([inplace], jnp.int32)])

@@ -223,11 +223,12 @@ def decode_step(
 
     With ``moe_counts`` it returns (logits, new cache, counts) as well:
     int32 (routed pairs of held experts, held experts that received a
-    pair), summed over the MoE layers.
+    pair, MoE layers that read their held experts in place), summed over
+    the MoE layers.
     """
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
     caches = _split_cache(cache)
-    counts = jnp.zeros(2, jnp.int32)
+    counts = jnp.zeros(3, jnp.int32)
     for key, _, moe in stacks(cfg):
         def body(x, scanned, moe=moe):
             lp, cache_l = scanned

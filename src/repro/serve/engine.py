@@ -19,9 +19,10 @@ decodes while the host waits for and handles the pass.  Counters
 ``engine.host_pulls``, one a pass (its transfer); ``engine.decode_ahead``,
 one a decode dispatched before its pass's read.  The decode of a model with
 experts also counts, summed over its MoE layers, the routed (token, expert)
-pairs of the experts held here and the held experts that received one; the
-two numbers ride in the pass's one transfer behind the tokens and move
-``moe.pairs_held`` and ``moe.experts_touched``.
+pairs of the experts held here, the held experts that received one and the
+layers that read their held experts in place; the three numbers ride in the
+pass's one transfer behind the tokens and move ``moe.pairs_held``,
+``moe.experts_touched`` and ``moe.inplace_layers``.
 """
 from __future__ import annotations
 
@@ -125,6 +126,7 @@ class ServeEngine:
                     if got.size > n:
                         obs.add("moe.pairs_held", int(got[n]))
                         obs.add("moe.experts_touched", int(got[n + 1]))
+                        obs.add("moe.inplace_layers", int(got[n + 2]))
                     for i, r in enumerate(wave):
                         if not done[i]:
                             gen[i].append(picked[i])

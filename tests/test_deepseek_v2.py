@@ -8,7 +8,8 @@ plain float32 reference the benchmark's check compares with.  Here, at a
 tiny size: the program's prefill and decode through the cache give the
 reference's logits, and each of those mechanisms, put back to its plain
 alternative, breaks that; the rotations agree; the expert shares add up to
-the uncut layer; the YaRN numbers are those of the published formulas; the
+the uncut layer; the held experts' dense path (read in place) and grouped
+path agree, and a layer takes the dense one up to its token bound; the YaRN numbers are those of the published formulas; the
 seeded weights and the parameter counts are pinned; the tuner's cache bytes
 per position and the engine's expert counters come from the model.
 """
@@ -25,8 +26,9 @@ from bench import lm
 from bench.references import deepseek_v2 as reference
 from repro import obs
 from repro.models.common import apply_rope, init_params, mlp_apply
-from repro.models.moe import (MoEConfig, moe_apply, moe_apply_dropless,
-                              moe_defs)
+from repro.models.moe import (DENSE_MAX_TOKENS, MoEConfig, held_dense,
+                              held_ragged, moe_apply, moe_apply_dropless,
+                              moe_defs, route)
 from repro.models.registry import build_model
 from repro.serve.autotune import stats_from_model
 from repro.serve.engine import Request, ServeEngine
@@ -135,15 +137,31 @@ def test_yarn_frequencies_and_softmax_factor_by_hand():
     assert yarn.softmax_factor == pytest.approx(1.5896, abs=1e-4)
 
 
+def held_part(helper, p, cfg, x):
+    """The held experts' part of ``x`` (B, S, D) through one path's helper:
+    (out, counts)."""
+    xt = x.reshape(-1, x.shape[-1])
+    _, gate_vals, gate_idx = route(p, cfg, xt)
+    out, counts = helper(p, cfg, xt, gate_vals, gate_idx)
+    return out.reshape(x.shape), counts
+
+
 def moe_case(cfg, p, x, path):
+    """(out, routed pairs of the held experts or None) of one path."""
     if path == "dropless":
-        return moe_apply_dropless(p, cfg, x)[0]
-    return moe_apply(p, cfg, x)[0]
+        out, counts = moe_apply_dropless(p, cfg, x)
+        return out, int(counts[0])
+    if path == "ragged":
+        out, counts = held_part(held_ragged, p, cfg, x)
+        return out + mlp_apply(p["shared"], x), int(counts[0])
+    return moe_apply(p, cfg, x)[0], None
 
 
 @pytest.mark.parametrize("norm_topk", [False, True])
-@pytest.mark.parametrize("path", ["dropless", "capacity"])
+@pytest.mark.parametrize("path", ["dropless", "capacity", "ragged"])
 def test_expert_shares_add_up_to_the_uncut_layer(path, norm_topk):
+    """``dropless`` takes ``held_dense`` at these 16 tokens; ``ragged``
+    reaches the grouped products through their own helper."""
     whole = MoEConfig(d_model=32, d_ff=16, n_experts=16, top_k=6, n_shared=2,
                       norm_topk=norm_topk)
     p = init_params(jax.random.PRNGKey(0), moe_defs(whole), jnp.float32)
@@ -155,13 +173,56 @@ def test_expert_shares_add_up_to_the_uncut_layer(path, norm_topk):
         cfg = whole._replace(share=(2 * i, 2))
         part = {**p, **{k: p[k][2 * i:2 * i + 2]
                         for k in ("w_gate", "w_up", "w_down")}}
-        total = total + moe_case(cfg, part, x, path)
-        if path == "dropless":
-            pairs += int(moe_apply_dropless(part, cfg, x)[1][0])
-    np.testing.assert_allclose(total, moe_case(whole, p, x, path),
+        out, held_pairs = moe_case(cfg, part, x, path)
+        total = total + out
+        if held_pairs is not None:
+            pairs += held_pairs
+    np.testing.assert_allclose(total, moe_case(whole, p, x, path)[0],
                                atol=1e-5)
-    if path == "dropless":
+    if path != "capacity":
         assert pairs == 2 * 8 * 6      # every (token, expert) pair, once
+
+
+# (experts, top-k, held share, gates renormalised): DeepSeek-V2-Lite's
+# share of 8 of 64 at top-6 with softmax gates, and every expert held at
+# top-1 (Llama-4-Scout's routing)
+HELD_CASES = {"share-8-of-64-top-6": (64, 6, (8, 8), False),
+              "all-held-top-1": (16, 1, (), True)}
+
+
+@pytest.mark.parametrize("tokens", [16, 200])
+@pytest.mark.parametrize("case", sorted(HELD_CASES))
+def test_dense_and_ragged_paths_agree(case, tokens):
+    n_experts, top_k, share, norm_topk = HELD_CASES[case]
+    cfg = MoEConfig(d_model=32, d_ff=16, n_experts=n_experts, top_k=top_k,
+                    norm_topk=norm_topk, share=share)
+    p = init_params(jax.random.PRNGKey(0), moe_defs(cfg), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, tokens, 32),
+                          jnp.float32)
+    dense, dense_counts = held_part(held_dense, p, cfg, x)
+    ragged, ragged_counts = held_part(held_ragged, p, cfg, x)
+    np.testing.assert_allclose(dense, ragged, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(dense_counts, ragged_counts)
+    pairs, touched = (int(c) for c in ragged_counts)
+    assert 0 < touched <= pairs <= tokens * top_k
+    if not share:
+        assert pairs == tokens * top_k   # every pair lands on a held expert
+
+
+@pytest.mark.parametrize("tokens, inplace", [(DENSE_MAX_TOKENS, 1),
+                                             (DENSE_MAX_TOKENS + 1, 0)])
+def test_dropless_layer_reads_in_place_up_to_its_token_bound(tokens, inplace):
+    cfg = MoEConfig(d_model=32, d_ff=16, n_experts=64, top_k=6, n_shared=2,
+                    norm_topk=False, share=(8, 8))
+    p = init_params(jax.random.PRNGKey(0), moe_defs(cfg), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, tokens, 32),
+                          jnp.float32)
+    out, counts = moe_apply_dropless(p, cfg, x)
+    helper = held_dense if inplace else held_ragged
+    want, want_counts = held_part(helper, p, cfg, x)
+    np.testing.assert_allclose(out, want + mlp_apply(p["shared"], x),
+                               rtol=1e-6, atol=1e-6)
+    assert counts.tolist() == want_counts.tolist() + [inplace]
 
 
 def checksum(leaf):
@@ -226,3 +287,5 @@ def test_engine_counts_expert_rows_in_its_one_transfer():
     assert 0 < moved("moe.pairs_held") <= 64
     assert 0 < moved("moe.experts_touched") <= min(
         moved("moe.pairs_held"), 4 * 2 * 8)
+    # decode calls of 2 tokens read every MoE layer's experts in place
+    assert moved("moe.inplace_layers") == 4 * 2
