@@ -1,13 +1,30 @@
-"""Attention variants: GQA/MQA (with optional QKV bias) and DeepSeek-style
-MLA (multi-head latent attention with compressed KV cache).
+"""Attention variants and the serving cache they own: GQA/MQA (with optional
+QKV bias) and DeepSeek-style MLA (multi-head latent attention with a
+compressed KV cache).
 
 Prefill/training uses memory-safe chunked ("flash-style") attention in pure
-JAX — the Pallas kernel in repro/kernels/attention is the TPU hot-path
-drop-in, validated against the same oracle.  Decode uses a dense matvec over
-the KV cache.
+JAX, skipping the causal upper triangle block by block — the Pallas kernel in
+repro/kernels/attention is the TPU hot-path drop-in, validated against the
+same oracle.  Decode uses a dense matvec over the KV cache.
 
-Cache layout (GQA):   {"k": (B, S_max, Hkv, D), "v": ..., "pos": int32}
-Cache layout (MLA):   {"c_kv": (B, S_max, R), "k_rope": (B, S_max, Dr)}
+The serving cache is laid out, filled and written here only; the model
+modules ask for it through ``attn_init_cache``, ``cache_from_prefill`` and
+``write_token`` and never name its entries.  One layer's cache:
+
+    GQA: {"k": (B, S_max, Hkv, D), "v": (B, S_max, Hkv, D), "pos": int32}
+    MLA: {"c_kv": (B, S_max, R), "k_rope": (B, S_max, Dr), "pos": int32}
+
+``k`` is stored rotated; ``c_kv`` is the (normed) latent and ``k_rope`` the
+rotated shared key.  ``pos`` is the number of filled positions.
+
+``attn_apply`` returns (output, entries), the entries under the cache's own
+keys: without a cache (prefill) those of the S positions it attended over,
+the very arrays it used, (B, S, ...); with one (decode) the new token's,
+(B, 1, ...), which it attends to without writing the cache.
+``cache_from_prefill`` pads a prefill's entries to ``S_max`` and sets
+``pos``; ``write_token`` writes a scan's stacked new-token entries of every
+layer into the stacked cache (leaves (L, B, S_max, ...)) at ``pos`` in one
+in-place token-slot write, and advances ``pos``.
 """
 from __future__ import annotations
 
@@ -19,11 +36,6 @@ import jax.numpy as jnp
 
 from repro.models.common import (YaRN, ParamDef, Params, apply_rope, dense,
                                  rms_norm)
-
-# §Perf switch: causal upper-triangle block skipping in train/prefill
-# attention (see _causal_block_attention).  Module-level so experiments can
-# A/B the paper-faithful baseline (False) against the optimized path.
-CAUSAL_BLOCK_SKIP = True
 
 
 class AttnConfig(NamedTuple):
@@ -91,6 +103,15 @@ def _causal_block_attention(
     return jnp.concatenate(outs, axis=1)
 
 
+def _attend(cfg: AttnConfig, q: jax.Array, k: jax.Array, v: jax.Array,
+            chunk: int, scale: Optional[float] = None) -> jax.Array:
+    """Prefill/train attention of S queries over the same S positions:
+    causal skips the upper triangle; the encoder's attends everywhere."""
+    if cfg.causal:
+        return _causal_block_attention(q, k, v, chunk=chunk, scale=scale)
+    return _chunked_attention(q, k, v, False, chunk=chunk, scale=scale)
+
+
 def _chunked_attention(
     q: jax.Array,   # (B, S, H, D)
     k: jax.Array,   # (B, T, Hkv, D)
@@ -155,9 +176,9 @@ def gqa_apply(
     cfg: AttnConfig,
     x: jax.Array,                       # (B, S, D)
     positions: Optional[jax.Array] = None,
-    cache: Optional[Dict] = None,       # decode: append + attend over cache
+    cache: Optional[Dict] = None,       # decode: attend over cache + token
     kv_chunk: int = 1024,
-) -> Tuple[jax.Array, Optional[Dict]]:
+) -> Tuple[jax.Array, Dict]:
     b, s, _ = x.shape
     h, hk, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = dense(x, p["wq"], p.get("bq")).reshape(b, s, h, hd)
@@ -168,16 +189,12 @@ def gqa_apply(
         pos = positions if positions is not None else jnp.arange(s)[None, :]
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
-        if cfg.causal and CAUSAL_BLOCK_SKIP:
-            out = _causal_block_attention(q, k, v, chunk=kv_chunk)
-        else:
-            out = _chunked_attention(q, k, v, cfg.causal, chunk=kv_chunk)
-        new_cache = None
+        out = _attend(cfg, q, k, v, kv_chunk)
     else:
         # single-token decode: attend over the stored prefix plus the current
-        # token WITHOUT rewriting the cache — the caller batches all layers'
-        # new K/V into one stacked cache update (in-place, outside the layer
-        # scan), so per-step cache traffic is read + one token-slot write.
+        # token WITHOUT rewriting the cache — ``write_token`` writes all
+        # layers' new K/V once, in place, outside the layer scan, so per-step
+        # cache traffic is read + one token-slot write.
         pos = cache["pos"]                          # scalar int32
         q = apply_rope(q, pos[None, None], cfg.rope_theta)
         k = apply_rope(k, pos[None, None], cfg.rope_theta)
@@ -201,19 +218,9 @@ def gqa_apply(
         v_self = v[:, 0][:, :, None, None, :]
         out = out + pr[..., -1][..., None] * v_self.astype(jnp.float32)
         out = jnp.moveaxis(out, 3, 1).reshape(b, 1, h, hd)
-        new_cache = {"k_new": k, "v_new": v}
 
     y = dense(out.reshape(b, s, h * hd).astype(x.dtype), p["wo"])
-    return y, new_cache
-
-
-def gqa_init_cache(cfg: AttnConfig, batch: int, max_seq: int, dtype):
-    hk, hd = cfg.n_kv_heads, cfg.head_dim
-    return {
-        "k": jnp.zeros((batch, max_seq, hk, hd), dtype),
-        "v": jnp.zeros((batch, max_seq, hk, hd), dtype),
-        "pos": jnp.int32(0),
-    }
+    return y, {"k": k, "v": v}
 
 
 # =============================================================================
@@ -246,7 +253,7 @@ def mla_apply(
     positions: Optional[jax.Array] = None,
     cache: Optional[Dict] = None,
     kv_chunk: int = 1024,
-) -> Tuple[jax.Array, Optional[Dict]]:
+) -> Tuple[jax.Array, Dict]:
     b, s, _ = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
     r, dr = cfg.kv_lora_rank, cfg.qk_rope_dim
@@ -276,14 +283,8 @@ def mla_apply(
         k_full = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope_r, (b, s, h, dr))], axis=-1
         )
-        if cfg.causal and CAUSAL_BLOCK_SKIP:
-            out = _causal_block_attention(q_full, k_full, v, chunk=kv_chunk,
-                                          scale=1.0 / denom)
-        else:
-            out = _chunked_attention(q_full, k_full, v, cfg.causal,
-                                     chunk=kv_chunk, scale=1.0 / denom)
-        # what a decode reads: the normed latent and the rotated key
-        new_cache = {"c_kv": c_kv, "k_rope": k_rope_r[:, :, 0, :]}
+        out = _attend(cfg, q_full, k_full, v, kv_chunk, scale=1.0 / denom)
+        k_rope_r = k_rope_r[:, :, 0, :]
     else:
         pos = cache["pos"]
         q_rope = rope(q_rope, pos[None, None])
@@ -317,15 +318,64 @@ def mla_apply(
         wv = p["w_uv"].reshape(r, h, dv)
         out = jnp.einsum("bshr,rhd->bshd", ctx.astype(wv.dtype), wv,
                          preferred_element_type=jnp.float32)
-        new_cache = {"c_kv_new": c_kv, "k_rope_new": k_rope_r}
 
     y = dense(out.reshape(b, s, h * dv).astype(x.dtype), p["wo"])
-    return y, new_cache
+    # what a decode reads: the normed latent and the rotated key
+    return y, {"c_kv": c_kv, "k_rope": k_rope_r}
 
 
-def mla_init_cache(cfg: AttnConfig, batch: int, max_seq: int, dtype):
-    return {
-        "c_kv": jnp.zeros((batch, max_seq, cfg.kv_lora_rank), dtype),
-        "k_rope": jnp.zeros((batch, max_seq, cfg.qk_rope_dim), dtype),
-        "pos": jnp.int32(0),
-    }
+# =============================================================================
+# The attention kind and the serving cache
+# =============================================================================
+def attn_defs(cfg: AttnConfig) -> Dict[str, ParamDef]:
+    return mla_defs(cfg) if cfg.kv_lora_rank else gqa_defs(cfg)
+
+
+def attn_apply(
+    p: Params,
+    cfg: AttnConfig,
+    x: jax.Array,
+    positions: Optional[jax.Array] = None,
+    cache: Optional[Dict] = None,
+    kv_chunk: int = 1024,
+) -> Tuple[jax.Array, Dict]:
+    """(output, cache entries) of GQA or, with a ``kv_lora_rank``, MLA."""
+    if cfg.kv_lora_rank:
+        with jax.named_scope("mla"):
+            return mla_apply(p, cfg, x, positions, cache, kv_chunk)
+    return gqa_apply(p, cfg, x, positions, cache, kv_chunk)
+
+
+def attn_init_cache(cfg: AttnConfig, batch: int, max_seq: int, dtype):
+    """One layer's empty cache."""
+    if cfg.kv_lora_rank:
+        shapes = {"c_kv": (batch, max_seq, cfg.kv_lora_rank),
+                  "k_rope": (batch, max_seq, cfg.qk_rope_dim)}
+    else:
+        kv = (batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+        shapes = {"k": kv, "v": kv}
+    cache = {key: jnp.zeros(shape, dtype) for key, shape in shapes.items()}
+    cache["pos"] = jnp.int32(0)
+    return cache
+
+
+def cache_from_prefill(entries: Dict, max_seq: int) -> Dict:
+    """One layer's cache after a prefill: its entries (B, S, ...) padded on
+    the sequence axis to ``max_seq``, and ``pos`` S."""
+    s = next(iter(entries.values())).shape[1]
+    cache = {key: jnp.pad(a, ((0, 0), (0, max_seq - s))
+                          + ((0, 0),) * (a.ndim - 2))
+             for key, a in entries.items()}
+    cache["pos"] = jnp.int32(s)
+    return cache
+
+
+def write_token(stacked: Dict, entries: Dict) -> Dict:
+    """The stacked cache (L, B, S_max, ...) with every layer's new-token
+    entries (L, B, 1, ...) written in place at ``pos``, and ``pos`` + 1."""
+    pos = stacked["pos"][0]
+    cache = {key: jax.lax.dynamic_update_slice(
+                 stacked[key], a, (0, 0, pos) + (0,) * (a.ndim - 3))
+             for key, a in entries.items()}
+    cache["pos"] = stacked["pos"] + 1
+    return cache

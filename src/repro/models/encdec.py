@@ -8,18 +8,17 @@ decoder layers (seamless-large sizing; noted in DESIGN.md).
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.distributed.api import shard_act
-from repro.models.attention import (AttnConfig, _chunked_attention, gqa_apply,
-                                    gqa_defs, gqa_init_cache)
-from repro.models.common import (ParamDef, Params, apply_rope,
-                                 cross_entropy_from_hidden, dense,
-                                 init_params, mlp_apply, mlp_defs, rms_norm,
+from repro.models.attention import (_chunked_attention, attn_apply, attn_defs,
+                                    attn_init_cache, cache_from_prefill,
+                                    write_token)
+from repro.models.common import (ParamDef, Params, cross_entropy_from_hidden,
+                                 dense, mlp_apply, mlp_defs, rms_norm,
                                  stack_defs)
 from repro.models.config import ArchConfig
 from repro.models.transformer import attn_config
@@ -39,13 +38,13 @@ def _xattn_defs(cfg: ArchConfig) -> Dict[str, ParamDef]:
 def encdec_defs(cfg: ArchConfig) -> Dict[str, Any]:
     enc_block = {
         "ln1": ParamDef((cfg.d_model,), ("embed",), init="ones"),
-        "attn": gqa_defs(attn_config(cfg)),
+        "attn": attn_defs(attn_config(cfg)),
         "ln2": ParamDef((cfg.d_model,), ("embed",), init="ones"),
         "mlp": mlp_defs(cfg.d_model, cfg.d_ff, gated=True),
     }
     dec_block = {
         "ln1": ParamDef((cfg.d_model,), ("embed",), init="ones"),
-        "attn": gqa_defs(attn_config(cfg)),
+        "attn": attn_defs(attn_config(cfg)),
         "ln_x": ParamDef((cfg.d_model,), ("embed",), init="ones"),
         "xattn": _xattn_defs(cfg),
         "ln2": ParamDef((cfg.d_model,), ("embed",), init="ones"),
@@ -72,7 +71,7 @@ def _encode(cfg: ArchConfig, params: Params, frames: jax.Array,
     x = shard_act(x, ("batch", None, None))
 
     def body(x, lp):
-        h, _ = gqa_apply(lp["attn"], acfg, rms_norm(x, lp["ln1"]))
+        h, _ = attn_apply(lp["attn"], acfg, rms_norm(x, lp["ln1"]))
         x = x + h
         x = x + mlp_apply(lp["mlp"], rms_norm(x, lp["ln2"]), cfg.activation)
         return shard_act(x, ("batch", None, None)), None
@@ -110,7 +109,7 @@ def encdec_loss(cfg: ArchConfig, params: Params, batch: Dict,
     x = shard_act(x, ("batch", None, None))
 
     def body(x, lp):
-        h, _ = gqa_apply(lp["attn"], acfg, rms_norm(x, lp["ln1"]))
+        h, _ = attn_apply(lp["attn"], acfg, rms_norm(x, lp["ln1"]))
         x = x + h
         kv = _enc_kv(cfg, lp["xattn"], enc_out)
         x = x + _cross_attend(cfg, lp["xattn"], rms_norm(x, lp["ln_x"]), kv)
@@ -126,7 +125,7 @@ def encdec_loss(cfg: ArchConfig, params: Params, batch: Dict,
 
 
 def encdec_init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype):
-    a1 = gqa_init_cache(attn_config(cfg), batch, max_seq, dtype)
+    a1 = attn_init_cache(attn_config(cfg), batch, max_seq, dtype)
     self_c = jax.tree.map(
         lambda a: jnp.broadcast_to(a, (cfg.dec_layers,) + a.shape).copy(), a1)
     hk, hd = cfg.n_kv_heads, cfg.eff_head_dim
@@ -143,21 +142,10 @@ def encdec_prefill(cfg: ArchConfig, params: Params, batch: Dict,
     enc_out = _encode(cfg, params, batch["frames"])
     acfg = attn_config(cfg)
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
-    b, s, _ = x.shape
-    pad = max_seq - s
-    hk, hd = cfg.n_kv_heads, cfg.eff_head_dim
 
     def body(x, lp):
-        h_in = rms_norm(x, lp["ln1"])
-        k = dense(h_in, lp["attn"]["wk"]).reshape(b, s, hk, hd)
-        k = apply_rope(k, jnp.arange(s)[None, :], cfg.rope_theta)
-        v = dense(h_in, lp["attn"]["wv"]).reshape(b, s, hk, hd)
-        self_c = {
-            "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-            "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-            "pos": jnp.int32(s),
-        }
-        h, _ = gqa_apply(lp["attn"], acfg, h_in)
+        h, entries = attn_apply(lp["attn"], acfg, rms_norm(x, lp["ln1"]))
+        self_c = cache_from_prefill(entries, max_seq)
         x = x + h
         kv = _enc_kv(cfg, lp["xattn"], enc_out)
         x = x + _cross_attend(cfg, lp["xattn"], rms_norm(x, lp["ln_x"]), kv)
@@ -177,8 +165,8 @@ def encdec_decode(cfg: ArchConfig, params: Params, cache: Dict, batch: Dict
 
     def body(x, scanned):
         lp, self_c, cross_c = scanned
-        h, new_kv = gqa_apply(lp["attn"], acfg, rms_norm(x, lp["ln1"]),
-                              cache=self_c)
+        h, new_kv = attn_apply(lp["attn"], acfg, rms_norm(x, lp["ln1"]),
+                               cache=self_c)
         x = x + h
         x = x + _cross_attend(cfg, lp["xattn"], rms_norm(x, lp["ln_x"]),
                               (cross_c["k"], cross_c["v"]))
@@ -187,15 +175,7 @@ def encdec_decode(cfg: ArchConfig, params: Params, cache: Dict, batch: Dict
 
     x, new_kv = jax.lax.scan(body, x,
                              (params["dec"], cache["self"], cache["cross"]))
-    sc = cache["self"]
-    pos = sc["pos"][0]
-    new_self = {
-        "k": jax.lax.dynamic_update_slice(
-            sc["k"], new_kv["k_new"], (0, 0, pos, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(
-            sc["v"], new_kv["v_new"], (0, 0, pos, 0, 0)),
-        "pos": sc["pos"] + 1,
-    }
+    new_self = write_token(cache["self"], new_kv)
     hidden = rms_norm(x, params["final_ln"])
     logits = dense(hidden, params["lm_head"])
     return logits, {"self": new_self, "cross": cache["cross"]}

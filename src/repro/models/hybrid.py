@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.api import shard_act
-from repro.models.attention import AttnConfig, gqa_apply, gqa_defs, gqa_init_cache
+from repro.models.attention import (attn_apply, attn_defs, attn_init_cache,
+                                    cache_from_prefill, write_token)
 from repro.models.common import (ParamDef, Params, cross_entropy_from_hidden,
                                  dense, mlp_apply, mlp_defs, rms_norm,
                                  stack_defs)
@@ -49,7 +50,7 @@ def zamba2_defs(cfg: ArchConfig) -> Dict[str, Any]:
     shared_block = {
         "ln1": ParamDef((cfg.d_model,), ("embed",), init="ones"),
         "ln2": ParamDef((cfg.d_model,), ("embed",), init="ones"),
-        "attn": gqa_defs(attn_config(cfg)),
+        "attn": attn_defs(attn_config(cfg)),
         "mlp": mlp_defs(cfg.d_model, cfg.d_ff, gated=True),
     }
     v = cfg.padded_vocab
@@ -63,9 +64,8 @@ def zamba2_defs(cfg: ArchConfig) -> Dict[str, Any]:
 
 
 def _zamba_shared_apply(cfg: ArchConfig, sp: Params, x, cache=None):
-    acfg = attn_config(cfg)
-    h, new_c = gqa_apply(sp["attn"], acfg, rms_norm(x, sp["ln1"]),
-                         cache=cache)
+    h, new_c = attn_apply(sp["attn"], attn_config(cfg),
+                          rms_norm(x, sp["ln1"]), cache=cache)
     x = x + h
     x = x + mlp_apply(sp["mlp"], rms_norm(x, sp["ln2"]), cfg.activation)
     return x, new_c
@@ -104,7 +104,7 @@ def zamba2_init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype):
     m1 = mamba2_init_cache(mcfg, batch, dtype)
     mcache = jax.tree.map(
         lambda a: jnp.broadcast_to(a, (n_super, per) + a.shape).copy(), m1)
-    a1 = gqa_init_cache(attn_config(cfg), batch, max_seq, dtype)
+    a1 = attn_init_cache(attn_config(cfg), batch, max_seq, dtype)
     acache = jax.tree.map(
         lambda a: jnp.broadcast_to(a, (n_super,) + a.shape).copy(), a1)
     return {"mamba": mcache, "attn": acache}
@@ -131,31 +131,17 @@ def zamba2_decode(cfg: ArchConfig, params: Params, cache: Dict, batch: Dict
 
     x, (new_m, new_kv) = jax.lax.scan(
         super_body, x, (params["supers"], cache["mamba"], cache["attn"]))
-    # one in-place token-slot write for all shared-attn cache layers
-    pos = cache["attn"]["pos"][0]
-    ac = cache["attn"]
-    new_attn = {
-        "k": jax.lax.dynamic_update_slice(
-            ac["k"], new_kv["k_new"], (0, 0, pos, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(
-            ac["v"], new_kv["v_new"], (0, 0, pos, 0, 0)),
-        "pos": ac["pos"] + 1,
-    }
     hidden = rms_norm(x, params["final_ln"])
     logits = dense(hidden, params["lm_head"])
-    return logits, {"mamba": new_m, "attn": new_attn}
+    return logits, {"mamba": new_m, "attn": write_token(cache["attn"], new_kv)}
 
 
 def zamba2_prefill(cfg: ArchConfig, params: Params, batch: Dict,
                    max_seq: int, **_) -> Tuple[jax.Array, Dict]:
     """Prefill via repeated decode is O(S²) — instead run the parallel form
-    while accumulating caches per layer (recompute-based, like transformer
-    prefill)."""
+    while accumulating caches per layer."""
     mcfg = mamba_config(cfg)
-    acfg = attn_config(cfg)
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
-    b, s, _ = x.shape
-    pad = max_seq - s
 
     def super_body(x, sb):
         def mamba_body(x, lp):
@@ -170,19 +156,8 @@ def zamba2_prefill(cfg: ArchConfig, params: Params, batch: Dict,
             return x + out, mc
 
         x, mcaches = jax.lax.scan(mamba_body, x, sb)
-        h = rms_norm(x, params["shared"]["ln1"])
-        from repro.models.common import apply_rope
-        hk, hd = acfg.n_kv_heads, acfg.head_dim
-        k = dense(h, params["shared"]["attn"]["wk"]).reshape(b, s, hk, hd)
-        k = apply_rope(k, jnp.arange(s)[None, :], cfg.rope_theta)
-        v = dense(h, params["shared"]["attn"]["wv"]).reshape(b, s, hk, hd)
-        acache = {
-            "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-            "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-            "pos": jnp.int32(s),
-        }
-        x, _ = _zamba_shared_apply(cfg, params["shared"], x)
-        return x, (mcaches, acache)
+        x, entries = _zamba_shared_apply(cfg, params["shared"], x)
+        return x, (mcaches, cache_from_prefill(entries, max_seq))
 
     x, (mcache, acache) = jax.lax.scan(super_body, x, params["supers"])
     hidden = rms_norm(x[:, -1:], params["final_ln"])

@@ -10,20 +10,18 @@ stack's layers under the key ``dense_blocks``.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.distributed.api import shard_act
-from repro.models.attention import (AttnConfig, gqa_apply, gqa_defs,
-                                    gqa_init_cache, mla_apply, mla_defs,
-                                    mla_init_cache)
-from repro.models.common import (ParamDef, Params, apply_rope,
-                                 cross_entropy_from_hidden, dense,
-                                 init_params, logical_specs, mlp_apply,
-                                 mlp_defs, rms_norm, stack_defs)
+from repro.models.attention import (AttnConfig, attn_apply, attn_defs,
+                                    attn_init_cache, cache_from_prefill,
+                                    write_token)
+from repro.models.common import (ParamDef, Params, cross_entropy_from_hidden,
+                                 dense, mlp_apply, mlp_defs, rms_norm,
+                                 stack_defs)
 from repro.models.config import ArchConfig
 from repro.models.moe import (MoEConfig, moe_apply,
                               moe_apply_dropless, moe_defs)
@@ -73,11 +71,10 @@ def stacks(cfg: ArchConfig) -> Tuple[Tuple[str, int, bool], ...]:
 # Parameter definitions
 # =============================================================================
 def block_defs(cfg: ArchConfig, moe: bool) -> Dict[str, Any]:
-    acfg = attn_config(cfg)
     defs: Dict[str, Any] = {
         "ln1": ParamDef((cfg.d_model,), ("embed",), init="ones"),
         "ln2": ParamDef((cfg.d_model,), ("embed",), init="ones"),
-        "attn": mla_defs(acfg) if cfg.kv_lora_rank else gqa_defs(acfg),
+        "attn": attn_defs(attn_config(cfg)),
     }
     if moe:
         defs["moe"] = moe_defs(moe_config(cfg))
@@ -106,17 +103,10 @@ def lm_defs(cfg: ArchConfig) -> Dict[str, Any]:
 # =============================================================================
 # Forward
 # =============================================================================
-def _attn(cfg: ArchConfig, p: Params, h: jax.Array, **kw):
-    acfg = attn_config(cfg)
-    if cfg.kv_lora_rank:
-        with jax.named_scope("mla"):
-            return mla_apply(p, acfg, h, **kw)
-    return gqa_apply(p, acfg, h, **kw)
-
-
 def _block_apply(cfg: ArchConfig, lp: Params, x: jax.Array,
                  kv_chunk: int, moe: bool) -> Tuple[jax.Array, jax.Array]:
-    h, _ = _attn(cfg, lp["attn"], rms_norm(x, lp["ln1"]), kv_chunk=kv_chunk)
+    h, _ = attn_apply(lp["attn"], attn_config(cfg), rms_norm(x, lp["ln1"]),
+                      kv_chunk=kv_chunk)
     x = x + h
     x = shard_act(x, ("batch", None, None))
     h = rms_norm(x, lp["ln2"])
@@ -184,9 +174,7 @@ def lm_loss(
 # Serving: prefill + decode
 # =============================================================================
 def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype) -> Dict:
-    acfg = attn_config(cfg)
-    one = (mla_init_cache(acfg, batch, max_seq, dtype) if cfg.kv_lora_rank
-           else gqa_init_cache(acfg, batch, max_seq, dtype))
+    one = attn_init_cache(attn_config(cfg), batch, max_seq, dtype)
 
     # stack along layers for scan: every leaf gets a leading L axis
     def stacked(n):
@@ -217,9 +205,8 @@ def decode_step(
 ) -> Tuple[jax.Array, ...]:
     """One-token decode: batch["tokens"]: (B, 1) -> (logits, new cache).
 
-    Layers are scanned; each layer emits only the NEW token's K/V.  The
-    stacked cache is updated ONCE after the scan (a single in-place
-    token-slot write instead of per-layer full-buffer rewrites).
+    Layers are scanned; each layer emits only the new token's cache
+    entries, which ``write_token`` writes once after the scan.
 
     With ``moe_counts`` it returns (logits, new cache, counts) as well:
     int32 (routed pairs of held experts, held experts that received a
@@ -232,8 +219,8 @@ def decode_step(
     for key, _, moe in stacks(cfg):
         def body(x, scanned, moe=moe):
             lp, cache_l = scanned
-            h, new_c = _attn(cfg, lp["attn"], rms_norm(x, lp["ln1"]),
-                             cache=cache_l)
+            h, new_c = attn_apply(lp["attn"], attn_config(cfg),
+                                  rms_norm(x, lp["ln1"]), cache=cache_l)
             x = x + h
             h = rms_norm(x, lp["ln2"])
             if moe:
@@ -247,7 +234,7 @@ def decode_step(
         if moe:
             out, c = out
             counts = counts + jnp.sum(c, axis=0)
-        caches[key] = update_stacked_cache(cfg, caches[key], out)
+        caches[key] = write_token(caches[key], out)
     x = rms_norm(x, params["final_ln"])
     w_out = params.get("lm_head")
     if w_out is None:
@@ -258,62 +245,23 @@ def decode_step(
     return logits, _join_cache(caches)
 
 
-def update_stacked_cache(cfg: ArchConfig, cache: Dict, new_kv: Dict) -> Dict:
-    """Write all layers' new-token K/V into the stacked cache at pos."""
-    pos = cache["pos"][0]
-    if cfg.kv_lora_rank:
-        c_kv = jax.lax.dynamic_update_slice(
-            cache["c_kv"], new_kv["c_kv_new"], (0, 0, pos, 0))
-        k_rope = jax.lax.dynamic_update_slice(
-            cache["k_rope"], new_kv["k_rope_new"], (0, 0, pos, 0))
-        return {"c_kv": c_kv, "k_rope": k_rope, "pos": cache["pos"] + 1}
-    k = jax.lax.dynamic_update_slice(
-        cache["k"], new_kv["k_new"], (0, 0, pos, 0, 0))
-    v = jax.lax.dynamic_update_slice(
-        cache["v"], new_kv["v_new"], (0, 0, pos, 0, 0))
-    return {"k": k, "v": v, "pos": cache["pos"] + 1}
-
-
 def prefill(
     cfg: ArchConfig, params: Params, batch: Dict, max_seq: int,
     kv_chunk: int = 1024,
 ) -> Tuple[jax.Array, Dict]:
     """Process the prompt, building the KV cache; returns last-pos logits.
 
-    Implemented as forward_hidden for the hidden states plus cache
-    construction per layer.  MLA caches what its own prefill returns (the
-    normed latent and the rotated key); GQA recomputes its K/V projections
-    (cheap relative to attention itself and keeps the scan carry small).
+    Each layer caches the entries its own attention returns.
     """
     acfg = attn_config(cfg)
     x = embed_inputs(cfg, params, batch)
     b, s, _ = x.shape
-    pad = max_seq - s
     caches = {}
     for key, _, moe in stacks(cfg):
         def body(x, lp, moe=moe):
-            h = rms_norm(x, lp["ln1"])
-            if cfg.kv_lora_rank:
-                h, c = _attn(cfg, lp["attn"], h)
-                cache_l = {
-                    "c_kv": jnp.pad(c["c_kv"], ((0, 0), (0, pad), (0, 0))),
-                    "k_rope": jnp.pad(c["k_rope"],
-                                      ((0, 0), (0, pad), (0, 0))),
-                    "pos": jnp.int32(s),
-                }
-            else:
-                hk, hd = acfg.n_kv_heads, acfg.head_dim
-                k = dense(h, lp["attn"]["wk"], lp["attn"].get("bk")).reshape(
-                    b, s, hk, hd)
-                k = apply_rope(k, jnp.arange(s)[None, :], cfg.rope_theta)
-                v = dense(h, lp["attn"]["wv"], lp["attn"].get("bv")).reshape(
-                    b, s, hk, hd)
-                cache_l = {
-                    "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                    "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                    "pos": jnp.int32(s),
-                }
-                h, _ = gqa_apply(lp["attn"], acfg, h)
+            h, entries = attn_apply(lp["attn"], acfg, rms_norm(x, lp["ln1"]),
+                                    kv_chunk=kv_chunk)
+            cache_l = cache_from_prefill(entries, max_seq)
             x = x + h
             h = rms_norm(x, lp["ln2"])
             if moe:
