@@ -11,20 +11,27 @@ The serving cache is laid out, filled and written here only; the model
 modules ask for it through ``attn_init_cache``, ``cache_from_prefill`` and
 ``write_token`` and never name its entries.  One layer's cache:
 
-    GQA: {"k": (B, S_max, Hkv, D), "v": (B, S_max, Hkv, D), "pos": int32}
+    GQA: {"k": (B, Hkv/g, S_max, g*D), "v": (B, Hkv/g, S_max, g*D),
+          "pos": int32}
     MLA: {"c_kv": (B, S_max, R), "k_rope": (B, S_max, Dr), "pos": int32}
 
 ``k`` is stored rotated; ``c_kv`` is the (normed) latent and ``k_rope`` the
-rotated shared key.  ``pos`` is the number of filled positions.
+rotated shared key.  ``pos`` is the number of filled positions.  In every
+leaf the sequence is the second-minor axis, so a token's entry is one row.
+A GQA row holds ``g = kv_group(cfg)`` kv heads side by side (kv head
+``j*g + i`` of row group ``j`` in lanes ``i*D:(i+1)*D``), as many as fill a
+128-lane vector row: with D < 128 the sequence would otherwise be the
+axis the compiler lays on the lanes, and a token's write would touch every
+tile of the cache.
 
 ``attn_apply`` returns (output, entries), the entries under the cache's own
-keys: without a cache (prefill) those of the S positions it attended over,
-the very arrays it used, (B, S, ...); with one (decode) the new token's,
-(B, 1, ...), which it attends to without writing the cache.
+keys and in its layout: without a cache (prefill) those of the S positions
+it attended over, (B, ..., S, W); with one (decode) the new token's,
+(B, ..., 1, W), which it attends to without writing the cache.
 ``cache_from_prefill`` pads a prefill's entries to ``S_max`` and sets
 ``pos``; ``write_token`` writes a scan's stacked new-token entries of every
-layer into the stacked cache (leaves (L, B, S_max, ...)) at ``pos`` in one
-in-place token-slot write, and advances ``pos``.
+layer into the stacked cache (leaves (L, B, ..., S_max, W)) at ``pos`` in
+one in-place row write, and advances ``pos``.
 """
 from __future__ import annotations
 
@@ -171,6 +178,21 @@ def _chunked_attention(
     return out
 
 
+def kv_group(cfg: AttnConfig) -> int:
+    """``g``, the kv heads a GQA cache row holds: the largest divisor of
+    ``n_kv_heads`` with g * head_dim <= 128 (1 where head_dim >= 128)."""
+    hk = cfg.n_kv_heads
+    return max((g for g in range(1, hk + 1)
+                if hk % g == 0 and g * cfg.head_dim <= 128), default=1)
+
+
+def _to_rows(cfg: AttnConfig, t: jax.Array) -> jax.Array:
+    """(B, S, Hkv, D) -> the cache's layout (B, Hkv/g, S, g*D)."""
+    b, s, hk, hd = t.shape
+    g = kv_group(cfg)
+    return jnp.swapaxes(t.reshape(b, s, hk // g, g * hd), 1, 2)
+
+
 def gqa_apply(
     p: Params,
     cfg: AttnConfig,
@@ -190,34 +212,40 @@ def gqa_apply(
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
         out = _attend(cfg, q, k, v, kv_chunk)
+        k, v = _to_rows(cfg, k), _to_rows(cfg, v)
     else:
         # single-token decode: attend over the stored prefix plus the current
         # token WITHOUT rewriting the cache — ``write_token`` writes all
         # layers' new K/V once, in place, outside the layer scan, so per-step
-        # cache traffic is read + one token-slot write.
+        # cache traffic is read + one row write.
         pos = cache["pos"]                          # scalar int32
         q = apply_rope(q, pos[None, None], cfg.rope_theta)
-        k = apply_rope(k, pos[None, None], cfg.rope_theta)
-        kc, vc = cache["k"], cache["v"]
-        t = kc.shape[1]
-        rep = h // hk
-        scale = 1.0 / math.sqrt(hd)
-        qf = (q * scale).reshape(b, 1, hk, rep, hd)
-        sij = jnp.einsum("bshrd,bthd->bhrst", qf, kc,
+        k = _to_rows(cfg, apply_rope(k, pos[None, None], cfg.rope_theta))
+        v = _to_rows(cfg, v)                        # (B, Hkv/g, 1, g*D)
+        kc, vc = cache["k"], cache["v"]             # (B, Hkv/g, S_max, g*D)
+        t = kc.shape[2]
+        g, rep = kv_group(cfg), h // hk
+        rows = hk // g
+        # the cache is read in place: each query head's vector sits in its
+        # kv head's D lanes of the row, zeros elsewhere (block-diagonal q),
+        # and keeps those D lanes of the weighted values
+        blocks = jnp.eye(g, dtype=q.dtype)[:, None, :, None]
+        qf = q * (1.0 / math.sqrt(hd))
+        qb = (qf.reshape(b, rows, g, rep, 1, hd) * blocks).reshape(
+            b, rows, g * rep, g * hd)
+        sij = jnp.einsum("bjqw,bjtw->bjqt", qb, kc,
                          preferred_element_type=jnp.float32)
-        valid = jnp.arange(t)[None, :] < pos
-        sij = jnp.where(valid[None, None, None], sij, -1e30)
-        s_self = jnp.einsum("bshrd,bshd->bhrs", qf, k,
+        valid = jnp.arange(t) < pos
+        sij = jnp.where(valid, sij, -1e30)
+        s_self = jnp.einsum("bjqw,bjsw->bjqs", qb, k,
                             preferred_element_type=jnp.float32)
-        sij = jnp.concatenate([sij, s_self[..., None]], axis=-1)
-        pr = jax.nn.softmax(sij, axis=-1)
-        out = jnp.einsum("bhrst,bthv->bhrsv",
-                         pr[..., :t].astype(vc.dtype), vc,
+        pr = jax.nn.softmax(jnp.concatenate([sij, s_self], axis=-1), axis=-1)
+        out = jnp.einsum("bjqt,bjtw->bjqw", pr[..., :t].astype(vc.dtype), vc,
                          preferred_element_type=jnp.float32)
-        # current token's value contribution: (B,Hk,rep,1,1)·(B,Hk,1,1,Dv)
-        v_self = v[:, 0][:, :, None, None, :]
-        out = out + pr[..., -1][..., None] * v_self.astype(jnp.float32)
-        out = jnp.moveaxis(out, 3, 1).reshape(b, 1, h, hd)
+        # current token's value: (B,Hkv/g,g*rep,1)·(B,Hkv/g,1,g*D)
+        out = out + pr[..., t:] * v.astype(jnp.float32)
+        out = jnp.sum(out.reshape(b, rows, g, rep, g, hd)
+                      * blocks.astype(jnp.float32), axis=4)
 
     y = dense(out.reshape(b, s, h * hd).astype(x.dtype), p["wo"])
     return y, {"k": k, "v": v}
@@ -352,7 +380,8 @@ def attn_init_cache(cfg: AttnConfig, batch: int, max_seq: int, dtype):
         shapes = {"c_kv": (batch, max_seq, cfg.kv_lora_rank),
                   "k_rope": (batch, max_seq, cfg.qk_rope_dim)}
     else:
-        kv = (batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+        g = kv_group(cfg)
+        kv = (batch, cfg.n_kv_heads // g, max_seq, g * cfg.head_dim)
         shapes = {"k": kv, "v": kv}
     cache = {key: jnp.zeros(shape, dtype) for key, shape in shapes.items()}
     cache["pos"] = jnp.int32(0)
@@ -360,22 +389,23 @@ def attn_init_cache(cfg: AttnConfig, batch: int, max_seq: int, dtype):
 
 
 def cache_from_prefill(entries: Dict, max_seq: int) -> Dict:
-    """One layer's cache after a prefill: its entries (B, S, ...) padded on
-    the sequence axis to ``max_seq``, and ``pos`` S."""
-    s = next(iter(entries.values())).shape[1]
-    cache = {key: jnp.pad(a, ((0, 0), (0, max_seq - s))
-                          + ((0, 0),) * (a.ndim - 2))
+    """One layer's cache after a prefill: its entries (B, ..., S, W) padded
+    on the sequence axis to ``max_seq``, and ``pos`` S."""
+    s = next(iter(entries.values())).shape[-2]
+    cache = {key: jnp.pad(a, ((0, 0),) * (a.ndim - 2)
+                          + ((0, max_seq - s), (0, 0)))
              for key, a in entries.items()}
     cache["pos"] = jnp.int32(s)
     return cache
 
 
 def write_token(stacked: Dict, entries: Dict) -> Dict:
-    """The stacked cache (L, B, S_max, ...) with every layer's new-token
-    entries (L, B, 1, ...) written in place at ``pos``, and ``pos`` + 1."""
+    """The stacked cache (L, B, ..., S_max, W) with every layer's new-token
+    entries (L, B, ..., 1, W) written in place at row ``pos``, and ``pos``
+    + 1."""
     pos = stacked["pos"][0]
     cache = {key: jax.lax.dynamic_update_slice(
-                 stacked[key], a, (0, 0, pos) + (0,) * (a.ndim - 3))
+                 stacked[key], a, (0,) * (a.ndim - 2) + (pos, 0))
              for key, a in entries.items()}
     cache["pos"] = stacked["pos"] + 1
     return cache

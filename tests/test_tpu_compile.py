@@ -1,4 +1,5 @@
-"""The main-path Pallas kernels compile for a described TPU v5e.
+"""The main-path Pallas kernels, and the serving decode's cache layout, compile
+for a described TPU v5e.
 
 No chip is needed: the TPU compiler is installed and compiles for a
 topology that is described, not attached.  This catches what interpret
@@ -7,6 +8,8 @@ overflow, ops Mosaic does not lower).  The topology is described inside a
 module fixture, never at import: only one process may load the TPU
 library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -78,3 +81,40 @@ def test_matmul_block_n_64_refused_for_v5e(one_chip):
     cfg = dict(BENCHMARKS["matmul"].default_config, BLOCK_N=64)
     with pytest.raises(Exception, match="divisible by 8 and 128"):
         _compile("matmul", cfg, one_chip)
+
+
+def test_qwen_decode_writes_its_token_in_one_cache_row_for_v5e(one_chip):
+    """qwen1.5-0.5b's decode (16 x 1024, the cache donated, as the engine
+    compiles it): no write into the K/V cache has the sequence axis
+    minor-most in its layout, where a one-token write would touch every
+    tile, and no temp buffer could hold a copy of a cache."""
+    from repro.configs import ARCHS
+    from repro.models.registry import build_model
+
+    batch, max_seq = 16, 1024
+    m = build_model(ARCHS["qwen1.5-0.5b"])
+
+    def struct(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(struct, jax.eval_shape(m.init,
+                                                 jax.random.PRNGKey(0)))
+    cache = jax.tree.map(struct, m.cache_specs(batch, max_seq))
+    tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip)
+
+    def decode(params, cache, batch):
+        logits, cache = m.decode(params, cache, batch)
+        return jnp.argmax(logits[:, -1:], axis=-1), cache
+
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, {"tokens": tokens}).compile()
+    shape = cache["k"].shape
+    seq_axis = shape.index(max_seq)
+    dims = ",".join(map(str, shape))
+    layouts = re.findall(
+        rf"= bf16\[{dims}\]\{{([\d,]+):[^}}]*\}} dynamic-update-slice\(",
+        compiled.as_text())
+    assert len(layouts) == 2, layouts              # the k and the v write
+    for layout in layouts:
+        assert int(layout.split(",")[0]) != seq_axis, layout
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
